@@ -22,6 +22,8 @@ chain steps, per-phase timers; warmup + median-of-N repeats) so the perf
 trajectory is tracked across PRs.
 
     python benchmarks/run.py [--smoke] [--repeats N] [--json PATH]
+
+A section that raises leaves an ``ERROR`` row and the run exits 1.
 """
 import argparse
 import json
@@ -517,6 +519,8 @@ def _pin_hash_seed() -> None:
 
 def main(argv=None) -> None:
     _pin_hash_seed()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="verification sections only, median-of-3 (stable "
@@ -569,6 +573,10 @@ def main(argv=None) -> None:
     with open(args.json, "w") as f:
         json.dump(out, f, indent=2, sort_keys=True)
     print(f"[bench] wrote {args.json}", file=sys.stderr)
+    if "errors" in out:
+        print(f"[bench] sections raised: {sorted(out['errors'])}",
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -12,8 +12,9 @@ Semantics:
   degrees their host case supports).
 * ``run(workers=0)`` (or 1) executes in-process sequentially;
   ``workers >= 2`` fans out on the shared fault-tolerant runtime
-  (:mod:`repro.runtime`): a supervised pool (fork start method where
-  available, spawn elsewhere) whose warmed workers persist on the Suite
+  (:mod:`repro.runtime`): a supervised pool (spawn once this process's
+  JAX backend is up, fork before that where the platform has it; workers
+  are pinned to the CPU backend) whose warmed workers persist on the Suite
   instance across ``run`` calls — call ``shutdown()`` or use the Suite as
   a context manager to release them.  Workers receive only
   ``(case, degree, bug)`` name triples and rebuild specs from the
@@ -222,9 +223,10 @@ class Suite:
         :func:`repro.runtime.resolve_cache` accepts (a directory path, an
         open :class:`CertificateCache`, True for the default location,
         None to consult ``$GRAPHGUARD_CACHE_DIR``).  ``mp_method``
-        overrides the worker start method (None = platform default;
-        "spawn" sidesteps fork-after-jax hazards in threaded hosts at the
-        cost of per-worker interpreter start-up)."""
+        overrides the worker start method (None = spawn once the JAX
+        backend is up, else fork where available; "spawn" sidesteps
+        fork-after-jax hazards in threaded hosts at the cost of per-worker
+        interpreter start-up)."""
         tasks = self.tasks()
         if workers is None:
             workers = min(4, len(tasks)) or 1

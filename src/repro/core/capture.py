@@ -37,47 +37,10 @@ import numpy as np
 import jax
 import jax.extend.core  # noqa: F401  (jax.extend requires explicit import)
 import jax.numpy as jnp
-from jax.sharding import AbstractMesh, PartitionSpec
+from jax.sharding import AbstractMesh, AxisType, PartitionSpec
 
 from . import terms as T
 from .terms import Term
-
-# --- shard_map API compatibility (jax >= 0.6 vs 0.4.x) ---------------------
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:  # jax 0.4.x: experimental namespace, check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _make_abstract_mesh(mesh_axes: dict) -> AbstractMesh:
-    axis_names = tuple(mesh_axes)
-    sizes = tuple(mesh_axes.values())
-    if hasattr(jax.sharding, "AxisType"):  # new-style constructor
-        return AbstractMesh(sizes, axis_names,
-                            axis_types=(jax.sharding.AxisType.Auto,)
-                            * len(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, sizes)))
-
-
-def _wrap_shard_map(fn, mesh, in_specs):
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=PartitionSpec(), check_vma=False)
-    except TypeError:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=PartitionSpec(), check_rep=False)
-
-
-def _eqn_in_specs(eqn) -> list:
-    """Per-operand PartitionSpecs of a shard_map eqn, across jax versions
-    (new: ``in_specs`` param; 0.4.x: ``in_names`` dim->axes dicts)."""
-    if "in_specs" in eqn.params:
-        return list(eqn.params["in_specs"])
-    specs = []
-    for names in eqn.params["in_names"]:
-        nd = max(names) + 1 if names else 0
-        specs.append(PartitionSpec(*(names.get(d) for d in range(nd))))
-    return specs
 
 
 # ---------------------------------------------------------------------------
@@ -200,8 +163,10 @@ def capture_spmd(fn: Callable, mesh_axes: dict, in_specs: Sequence,
     and lower the unwrapped body to a single-rank :class:`Graph` (collectives
     kept as symbolic ops for ``expand_spmd`` to instantiate)."""
     axis_names = tuple(mesh_axes)
-    mesh = _make_abstract_mesh(mesh_axes)
-    sm = _wrap_shard_map(fn, mesh, tuple(in_specs))
+    mesh = AbstractMesh(tuple(mesh_axes.values()), axis_names,
+                        axis_types=(AxisType.Auto,) * len(axis_names))
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=tuple(in_specs),
+                       out_specs=PartitionSpec(), check_vma=False)
     closed = jax.make_jaxpr(sm)(*avals)
     # unwrap the single shard_map eqn
     eqn = None
@@ -216,7 +181,7 @@ def capture_spmd(fn: Callable, mesh_axes: dict, in_specs: Sequence,
     # names/specs per eqn invar, and mark const positions.
     outer_pos = {v: i for i, v in enumerate(closed.jaxpr.invars)}
     const_map = dict(zip(closed.jaxpr.constvars, closed.consts))
-    eqn_specs = _eqn_in_specs(eqn)
+    eqn_specs = list(eqn.params["in_specs"])
     inner_names, const_positions = [], {}
     arg_names, arg_specs = [], []
     for pos, atom in enumerate(eqn.invars):

@@ -1,20 +1,23 @@
 """Flash-attention forward Pallas TPU kernel (online softmax).
 
 Grid: (batch*heads, q_blocks). Each program holds one (block_q, hd) query
-tile in VMEM and streams K/V tiles of (block_k, hd) from HBM, maintaining
-the running max / normalizer (m, l) of the online-softmax recurrence — the
-TPU adaptation of the FlashAttention schedule: instead of CUDA warps and
-shared-memory tiles, tiles are MXU-aligned (block_q, block_k multiples of
-128 when the sequence allows) VMEM blocks, and the inner K loop is a
-``lax.fori_loop`` inside the kernel body so the working set stays
-O(block_q * (hd + block_k)).
+tile and the *whole* (S, hd) K and V of its batch-head in VMEM, and walks
+K/V tiles of (block_k, hd) out of those VMEM blocks with a
+``lax.fori_loop``, keeping the running max / normalizer (m, l) of the
+online-softmax recurrence. Tiles are MXU-aligned (block_q, block_k
+multiples of 128 when the sequence allows).
+
+Because the K/V blocks span the sequence, VMEM bounds S: the working set
+is O(S * hd), not O(block_q * (hd + block_k)). :func:`max_seq_len` gives
+the longest S this kernel accepts, and the wrapper raises ``ValueError``
+above it rather than let the chip's compiler fail on VMEM. Streaming K/V
+over the grid would lift the bound.
 
 Causal masking skips fully-masked K tiles via the loop upper bound.
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -22,24 +25,33 @@ from jax.experimental import pallas as pl
 
 NEG_INF = -1e30
 
+# Largest (S, hd) K or V block, lane-padded to 128, that v5e's scoped VMEM
+# takes with both inputs double-buffered: 3 MiB blocks compile, 4 MiB
+# blocks are refused (tests/test_tpu_compile.py compiles both sides).
+KV_BLOCK_MAX_BYTES = 3 * 2**20
+
+
+def max_seq_len(hd: int, dtype) -> int:
+    """Longest sequence whose K/V blocks fit VMEM at head dim ``hd``."""
+    lanes = -(-hd // 128) * 128
+    return KV_BLOCK_MAX_BYTES // (lanes * jnp.dtype(dtype).itemsize)
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
                   scale: float):
     _, bq, hd = q_ref.shape
     Sk = k_ref.shape[1]
-    # size-1 leading slices (not int indices): int ref-indices break the
-    # interpret-mode discharge rule on older jax (0.4.x)
-    q = pl.load(q_ref, (pl.dslice(0, 1), slice(None), slice(None)))[0] \
-        .astype(jnp.float32) * scale
+    q = q_ref[0, :, :].astype(jnp.float32) * scale
     iq = pl.program_id(1)
+    # Mosaic contracts f32 operands at bf16 precision unless told otherwise
+    # (a v5e's f32 output was 1.2e-2 off the reference); f32 inputs get f32
+    prec = jax.lax.Precision.HIGHEST if q_ref.dtype == jnp.float32 else None
 
     def body(ik, carry):
         acc, m, l = carry
-        k = pl.load(k_ref, (pl.dslice(0, 1), pl.dslice(ik * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(0, 1), pl.dslice(ik * block_k, block_k),
-                            slice(None)))[0].astype(jnp.float32)
-        s = q @ k.T                                      # (bq, bk)
+        k = k_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(ik * block_k, block_k), :].astype(jnp.float32)
+        s = jnp.dot(q, k.T, precision=prec)              # (bq, bk)
         if causal:
             qpos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 0)
             kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (bq, block_k), 1)
@@ -48,7 +60,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         p = jnp.exp(s - m_new[:, None])
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + p.sum(axis=-1)
-        acc = acc * alpha[:, None] + p @ v
+        acc = acc * alpha[:, None] + jnp.dot(p, v, precision=prec)
         return acc, m_new, l_new
 
     acc0 = jnp.zeros((bq, hd), jnp.float32)
@@ -61,7 +73,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, causal: bool,
         n_k = Sk // block_k
     acc, m, l = jax.lax.fori_loop(0, n_k, body, (acc0, m0, l0))
     out = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-    pl.store(o_ref, (pl.dslice(0, 1), slice(None), slice(None)), out[None])
+    o_ref[0, :, :] = out
 
 
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
@@ -69,6 +81,11 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
                     interpret: bool = False):
     """q,k,v: (B, S, H, hd) (same head count; expand GQA beforehand)."""
     B, S, H, hd = q.shape
+    if S > max_seq_len(hd, k.dtype):
+        raise ValueError(
+            f"flash_attention holds K/V of the whole sequence in VMEM: "
+            f"S={S} exceeds the longest that fits, "
+            f"{max_seq_len(hd, k.dtype)} at hd={hd} in {k.dtype}")
     scale = scale or hd ** -0.5
     bq = min(block_q, S)
     bk = min(block_k, S)

@@ -1,7 +1,10 @@
-"""Training launcher: build mesh + shardings and run the training loop.
+"""Training launcher: build the model and optimizer and run the training loop.
 
     PYTHONPATH=src python -m repro.launch.train --arch gpt --steps 100
-(CPU demo runs the reduced config; on a real TPU pod pass --full.)
+
+Without ``--full`` it trains the reduced (smoke-test) variant of the
+architecture, which runs on the CPU; ``--full`` trains the published
+configuration, and one TPU v5e chip holds GPT at full width.
 """
 import argparse
 
@@ -12,18 +15,20 @@ from ..models import registry
 from ..optim import adamw
 from ..train.loop import TrainConfig, make_train_step
 from ..checkpoint import save_checkpoint
+from .compile_cache import enable_compile_cache
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt")
     ap.add_argument("--steps", type=int, default=50)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--full", action="store_true",
-                    help="use the full config (needs a real pod)")
+                    help="use the published config (one v5e chip holds GPT)")
     ap.add_argument("--ckpt", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
     cfg = registry.load_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()

@@ -123,16 +123,41 @@ class TaskOutcome:
         return info
 
 
+def _pin_worker() -> None:
+    """Pool initializer: pin the worker's JAX to the CPU backend.
+
+    Workers do host work only.  A chip belongs to one process, and the
+    parent may hold it: a worker that opened the accelerator would fail on
+    its lock or hang, and the pool would hide that as a degraded run.  The
+    pin must precede the worker's first JAX operation.
+    """
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+
+
 def _warm_worker() -> None:
-    """Pool initializer: pay the per-process jax backend cost up front.
+    """Pool initializer: pin to the CPU, then pay the per-process jax
+    backend cost up front.
 
     jax drops its XLA client cache in forked children (and spawn starts
     cold), so the first jax op in a worker costs hundreds of ms.  Doing it
     in the initializer moves that cost off the first task's critical path
     and lets a reused pool serve later runs at steady-state speed.
     """
+    _pin_worker()
     import jax.numpy as jnp
     (jnp.zeros((1,)) + 1).block_until_ready()
+
+
+def default_start_method() -> str:
+    """``spawn`` once this process's JAX backend is up, else ``fork``
+    where the platform has it: a forked child would inherit a live
+    backend client (and with it the parent's hold on the chip)."""
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() \
+            or "fork" not in multiprocessing.get_all_start_methods():
+        return "spawn"
+    return "fork"
 
 
 def terminate_pool(pool: ProcessPoolExecutor) -> None:
@@ -287,14 +312,13 @@ class SupervisedPool:
             raise ValueError("SupervisedPool needs workers >= 1; use "
                              "execute_inline for in-process runs")
         if mp_method is None:
-            methods = multiprocessing.get_all_start_methods()
-            mp_method = "fork" if "fork" in methods else "spawn"
+            mp_method = default_start_method()
         self.workers = workers
         self.mp_method = mp_method
         self.max_retries = max_retries
         self.backoff_s = backoff_s
         self.heartbeat_s = heartbeat_s
-        self._initializer = _warm_worker if warm else None
+        self._initializer = _warm_worker if warm else _pin_worker
         self._ctx = multiprocessing.get_context(mp_method)
         self._executor: Optional[ProcessPoolExecutor] = None
         self._manager = None

@@ -360,7 +360,8 @@ def test_suite_deterministic_across_workers_and_opt():
             with Suite(cases=cases, degrees=(2,),
                        engine_opts={"optimizations": opts}) as s:
                 summaries.append(
-                    json.dumps(s.run(workers=workers).stable_summary(),
+                    json.dumps(s.run(workers=workers,
+                                     mp_method="fork").stable_summary(),
                                sort_keys=True))
     assert len(set(summaries)) == 1, "results varied with workers/opt"
 
@@ -376,7 +377,8 @@ def test_suite_per_task_timeout():
         raise AssertionError
     try:
         with Suite(cases=["_sleepy", "ln_grad"], degrees=(2,)) as s:
-            result = s.run(workers=2, timeout_s=2.0)
+            # fork: the child must inherit the test-registered strategy
+            result = s.run(workers=2, timeout_s=2.0, mp_method="fork")
         by_case = {r.case: r for r in result}
         assert by_case["_sleepy"].verdict == "timeout"
         assert not by_case["_sleepy"].ok

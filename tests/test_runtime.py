@@ -24,6 +24,7 @@ from repro.runtime import (CertificateCache, DEFAULT_CACHE_DIR, PoolUnavailable,
                            chaos, execute_inline, obligation_cache_key,
                            resolve_cache, run_tasks, strategy_cache_key)
 from repro.runtime.cache import ENV_CACHE_DIR, _line_for
+from repro.runtime.pool import default_start_method
 
 HAVE_FORK = "fork" in multiprocessing.get_all_start_methods()
 needs_fork = pytest.mark.skipif(not HAVE_FORK,
@@ -67,9 +68,16 @@ def _task(key, fn=_report, args=None, **kw):
     return RuntimeTask(key=key, fn=fn, args=args or (key,), **kw)
 
 
+def _jax_platforms(tag):
+    import jax
+    return jax.config.jax_platforms
+
+
 # these tasks never touch jax, so pool tests skip the jax warm-up
-# initializer (warm=False) — forked workers stay pure-python
-POOL_KW = {"warm": False}
+# initializer (warm=False) — forked workers stay pure-python.  Fork is
+# asked for by name: once the test process's JAX backend is up, the pool's
+# default start method is spawn.
+POOL_KW = {"warm": False, "mp_method": "fork"}
 
 
 # ---------------------------------------------------------------------------
@@ -305,12 +313,27 @@ class TestPool:
         """Liveness regression: a worker that wedges before its first
         heartbeat (e.g. on a fork-inherited lock) must burn the task's
         budget from executor pick-up, not hang execute() forever."""
-        with SupervisedPool(2, warm=False) as pool:
+        with SupervisedPool(2, warm=False, mp_method="fork") as pool:
             pool._initializer = _wedge_forever
             out = pool.execute([_task("stuck", budget_s=2.0)])
         assert out["stuck"].status == "timeout"
         assert "wedged during startup" in out["stuck"].error
         assert out["stuck"].wall_s >= 1.5
+
+    def test_spawn_worker_pinned_to_cpu(self, monkeypatch):
+        """Workers never open an accelerator the parent may hold: with no
+        JAX_PLATFORMS in the environment they start from, the pool's
+        initializer still pins their JAX to the CPU backend."""
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        out = run_tasks([_task("w", fn=_jax_platforms)], workers=2,
+                        mp_method="spawn", warm=False)
+        assert out["w"].ok and out["w"].value == "cpu"
+
+    def test_default_start_method_spawns_once_backend_is_up(self):
+        import jax.numpy as jnp
+        (jnp.zeros((1,)) + 1).block_until_ready()
+        assert default_start_method() == "spawn"
+        assert SupervisedPool(2, warm=False).mp_method == "spawn"
 
     def test_degrades_inline_when_pool_unavailable(self, monkeypatch):
         pool = SupervisedPool(2, warm=False)

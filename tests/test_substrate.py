@@ -6,9 +6,12 @@ import tempfile
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.data.pipeline import SyntheticTextDataset
+from repro.launch import compile_cache
+from repro.launch import train as train_launcher
 from repro.models import registry
 from repro.optim import adamw
 from repro.optim.adamw import AdamWConfig
@@ -82,3 +85,34 @@ def test_grad_accum_matches_full_batch():
         np.testing.assert_allclose(np.asarray(a, np.float32),
                                    np.asarray(b, np.float32),
                                    rtol=2e-2, atol=2e-3)
+
+
+@pytest.fixture
+def cache_dir_config():
+    """Restore JAX's compile-cache directory after the test."""
+    old = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_compile_cache_defers_to_env(monkeypatch, cache_dir_config):
+    monkeypatch.setenv(compile_cache.ENV_DIR, "/somewhere/else")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_compile_cache() == "/somewhere/else"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_repo(monkeypatch, cache_dir_config):
+    monkeypatch.delenv(compile_cache.ENV_DIR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    assert compile_cache.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_train_launcher_runs_in_process(monkeypatch, capsys):
+    monkeypatch.setattr(train_launcher, "enable_compile_cache", lambda: None)
+    train_launcher.main(["--arch", "gpt", "--steps", "1", "--batch", "2",
+                         "--seq", "16"])
+    loss = float(capsys.readouterr().out.split()[-1])
+    assert np.isfinite(loss)
