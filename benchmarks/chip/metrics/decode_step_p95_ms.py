@@ -1,0 +1,10 @@
+"""The 95th percentile of the gap between tokens: the host's time from one
+step's dispatch to its tokens read back, over every step of the window.
+Each step ends in reading its tokens back, as a server streams them."""
+import numpy as np
+
+
+def read(run):
+    if run.cell.traffic["driver"] != "decode" or not run.records["steps"]:
+        return None
+    return float(np.percentile(run.records["step_s"], 95)) * 1e3

@@ -1,0 +1,15 @@
+"""The decode step's share of the chip's HBM bandwidth, in percent: the
+bytes each step needs (every weight, the K/V cache up to its position
+only), summed over the window's steps, over the window, over the peak."""
+import harness
+
+
+def read(run):
+    if run.cell.traffic["driver"] != "decode" or not run.records["steps"]:
+        return None
+    c, t = run.cell.config, run.cell.traffic
+    n = harness.counts(c["family"])
+    need = sum(n.decode_bytes(c, t["batch"], p)
+               for p in run.records["positions"])
+    peak = harness.peaks(run.device_kind)["hbm_bytes_per_s"]
+    return 100.0 * need / run.window_s / peak
