@@ -84,25 +84,44 @@ class _engine_opts:
         return False
 
 
+def prove(seq_fn, dist_fn, mesh_axes, in_specs, avals, input_names,
+          eo: _engine_opts, case: str, phase_s: Optional[dict] = None):
+    """Capture G_s and G_d, expand the SPMD capture into per-rank G_d and
+    R_i, and run relation inference (raising).  Returns ``(gs, gd,
+    certificate)``.
+
+    Each phase is a span (``capture`` with ``graph`` gs or gd, ``expand``,
+    ``infer``).  Capture and expansion are timed always, as the engine
+    times its phases; their seconds go to ``phase_s["capture"]`` and
+    ``phase_s["expand"]`` when ``phase_s`` is given, before inference
+    can raise."""
+    t0 = time.perf_counter()
+    with obs_trace.span("capture", cat="capture", graph="gs", case=case):
+        gs = capture(seq_fn, list(avals), list(input_names))
+    with obs_trace.span("capture", cat="capture", graph="gd", case=case):
+        cap = capture_spmd(dist_fn, mesh_axes, list(in_specs), list(avals),
+                           list(input_names))
+    t1 = time.perf_counter()
+    with obs_trace.span("expand", cat="capture", case=case):
+        gd, r_i = expand_spmd(cap)
+    if phase_s is not None:
+        phase_s["capture"] = t1 - t0
+        phase_s["expand"] = time.perf_counter() - t1
+    with obs_trace.span("infer", cat="engine", case=case):
+        return gs, gd, check_refinement(gs, gd, r_i,
+                                        max_nodes=eo.max_nodes,
+                                        explain=eo.explain)
+
+
 def run_spec(spec: StrategySpec, *, engine_opts: Optional[dict] = None
              ) -> Certificate:
     """Capture G_s/G_d, derive R_i, and run relation inference (raising)."""
     if not isinstance(engine_opts, _engine_opts):
         engine_opts = _engine_opts(engine_opts)
     with engine_opts as eo:
-        with obs_trace.span("capture", cat="capture", graph="gs",
-                            case=spec.name):
-            gs = capture(spec.seq_fn, list(spec.avals),
-                         list(spec.input_names))
-        with obs_trace.span("capture", cat="capture", graph="gd",
-                            case=spec.name):
-            cap = capture_spmd(spec.dist_fn, spec.mesh_axes,
-                               list(spec.in_specs), list(spec.avals),
-                               list(spec.input_names))
-            gd, r_i = expand_spmd(cap)
-        with obs_trace.span("infer", cat="engine", case=spec.name):
-            return check_refinement(gs, gd, r_i, max_nodes=eo.max_nodes,
-                                    explain=eo.explain)
+        return prove(spec.seq_fn, spec.dist_fn, spec.mesh_axes,
+                     spec.in_specs, spec.avals, spec.input_names, eo,
+                     spec.name)[2]
 
 
 def verify(spec_or_name: Union[str, StrategySpec], *,

@@ -20,9 +20,8 @@ from functools import partial
 from typing import Dict, Optional, Tuple, Union
 
 from ..api.report import Report
-from ..api.runner import _engine_opts
-from ..core import (RefinementError, capture, capture_spmd, check_refinement,
-                    expand_spmd)
+from ..api.runner import _engine_opts, prove
+from ..core import RefinementError
 from ..core.terms import pretty
 from ..models.config import ModelConfig
 from ..models.registry import load_config
@@ -43,6 +42,39 @@ def _expected_for(ob: Obligation) -> str:
             if dict(ob.structure).get("bug", "-") != "-" else "certificate")
 
 
+def seam_check(ob: Obligation, gs, gd, cert) -> Tuple[list, bool]:
+    """Each distributed output must assemble exactly as its output
+    PartitionSpec promises the next block's input relation (or the next
+    decode step's cache).  Returns the per-output rows and whether all
+    hold."""
+    with obs_trace.span("seam", cat="engine"):
+        n_ranks = 1
+        for _, s in ob.mesh_axes:
+            n_ranks *= s
+        seams, seams_ok = [], True
+        for j, (out_name, ospec) in enumerate(zip(gs.outputs,
+                                                  ob.out_specs)):
+            gd_out = gd.outputs[j * n_ranks]
+            base = gd_out.split("@")[0]
+            expect = expected_output_relation(
+                base, gd.shapes[gd_out], gd.dtypes[gd_out], ospec,
+                dict(ob.mesh_axes))
+            got = cert.r_o.get(out_name)
+            ok = got is expect           # Terms are hash-consed: identity
+            seams_ok &= ok
+            seams.append({"output": out_name, "ok": ok,
+                          "expected": pretty(expect, 999),
+                          "got": None if got is None else pretty(got, 999)})
+        return seams, seams_ok
+
+
+def certified_stats(cert_json: dict, phase_s: dict) -> dict:
+    """The certificate's stats with capture and expansion in ``phase_s``."""
+    stats = dict(cert_json["stats"])
+    stats["phase_s"] = {**stats.get("phase_s", {}), **phase_s}
+    return stats
+
+
 def _verify_obligation(ob: Obligation, name: str, expected: str,
                        engine_opts: Optional[dict] = None) -> dict:
     """Verify one obligation; returns a JSON-ready nested Report dict with
@@ -50,22 +82,19 @@ def _verify_obligation(ob: Obligation, name: str, expected: str,
     spec = ob.to_strategy_spec(
         name=name, expected=expected,
         bug=None if expected == "certificate" else "wrong_spec")
+    phase_s: dict = {}
     t0 = time.perf_counter()
     try:
         with _engine_opts(engine_opts) as eo:
-            gs = capture(spec.seq_fn, list(spec.avals),
-                         list(spec.input_names))
-            cap = capture_spmd(spec.dist_fn, spec.mesh_axes,
-                               list(spec.in_specs), list(spec.avals),
-                               list(spec.input_names))
-            gd, r_i = expand_spmd(cap)
-            cert = check_refinement(gs, gd, r_i, max_nodes=eo.max_nodes,
-                                    explain=eo.explain)
+            gs, gd, cert = prove(spec.seq_fn, spec.dist_fn, spec.mesh_axes,
+                                 spec.in_specs, spec.avals,
+                                 spec.input_names, eo, name, phase_s)
     except RefinementError as e:
         return Report(
             case=name, degree=spec.degree, bug=spec.bug,
             verdict="refinement_error", expected=expected,
             ok=expected == "refinement_error", localization=e.payload(),
+            stats={"phase_s": phase_s},
             explanation=getattr(e, "explanation", None),
             wall_s=round(time.perf_counter() - t0, 6)).to_json()
     except Exception as e:  # noqa: BLE001 — capture/engine failure -> verdict
@@ -75,30 +104,13 @@ def _verify_obligation(ob: Obligation, name: str, expected: str,
             error=f"{type(e).__name__}: {e}",
             wall_s=round(time.perf_counter() - t0, 6)).to_json()
 
-    # seam check: each distributed output must assemble exactly as its
-    # output PartitionSpec promises the next block's input relation
-    n_ranks = 1
-    for _, s in ob.mesh_axes:
-        n_ranks *= s
-    seams, seams_ok = [], True
-    for j, (out_name, ospec) in enumerate(zip(gs.outputs, ob.out_specs)):
-        gd_out = gd.outputs[j * n_ranks]
-        base = gd_out.split("@")[0]
-        expect = expected_output_relation(
-            base, gd.shapes[gd_out], gd.dtypes[gd_out], ospec,
-            dict(ob.mesh_axes))
-        got = cert.r_o.get(out_name)
-        ok = got is expect               # Terms are hash-consed: identity
-        seams_ok &= ok
-        seams.append({"output": out_name, "ok": ok,
-                      "expected": pretty(expect, 999),
-                      "got": None if got is None else pretty(got, 999)})
+    seams, seams_ok = seam_check(ob, gs, gd, cert)
     cert_json = cert.to_json()
     d = Report(
         case=name, degree=spec.degree, bug=spec.bug,
         verdict="certificate", expected=expected,
         ok=expected == "certificate" and seams_ok,
-        r_o=cert_json["r_o"], stats=cert_json["stats"],
+        r_o=cert_json["r_o"], stats=certified_stats(cert_json, phase_s),
         explanation=cert.explanation,
         wall_s=round(time.perf_counter() - t0, 6)).to_json()
     d["seams"] = seams
@@ -218,11 +230,13 @@ def check_model(model: Union[str, ModelConfig], plan: Union[str, MeshPlan],
     one-block edit re-proves only the changed obligation.
     """
     t0 = time.perf_counter()
-    dec = decompose(model, plan, bug=bug, bug_layer=bug_layer)
+    with obs_trace.span("decompose", cat="capture"):
+        dec = decompose(model, plan, bug=bug, bug_layer=bug_layer)
     obs_trace.event("dedup", cat="engine", subsystem="modelcheck",
                     total=dec.total_blocks, unique=dec.n_unique)
     reports, used, cache_stats, pstats = run_obligations(
         dec, workers=workers, engine_opts=engine_opts,
         timeout_s=timeout_s, cache=cache)
-    return stitch(dec, reports, time.perf_counter() - t0, used,
-                  cache_stats=cache_stats, pool=pstats)
+    with obs_trace.span("stitch", cat="engine"):
+        return stitch(dec, reports, time.perf_counter() - t0, used,
+                      cache_stats=cache_stats, pool=pstats)

@@ -8,6 +8,12 @@ Layers are grouped by the attention *pattern* (e.g. 5 local + 1 global) and
 scanned over pattern groups; any remainder layers get their own unscanned
 parameter stack. Per-role KV caches (ring-buffer for "local" layers, linear
 for "global") keep decode memory at the architecture's true footprint.
+
+The device work of a step is named with ``jax.named_scope``: ``embed``,
+``layers`` (the layer scan and loops), ``attn`` (with ``kv_cache`` and
+``attend`` inside it, from ``layers``), ``mlp`` and ``head``.  Scopes
+travel in the compiled HLO's ``op_name`` metadata and change no
+instruction; the benchmark's ``scopes.py`` sums device time per scope.
 """
 from __future__ import annotations
 
@@ -59,12 +65,14 @@ def _role_window(cfg, role):
 # ---------------------------------------------------------------------------
 
 def _apply_block(p, cfg, x, positions, angles, role, collect_kv=False):
-    h, kv_ = L.attention(p["attn"], cfg, L.rmsnorm(x, p["pre_attn"],
-                                                   cfg.norm_eps),
-                         positions, causal=True,
-                         window=_role_window(cfg, role), angles=angles)
-    x = x + h
-    x = x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
+    with jax.named_scope("attn"):
+        h, kv_ = L.attention(p["attn"], cfg, L.rmsnorm(x, p["pre_attn"],
+                                                       cfg.norm_eps),
+                             positions, causal=True,
+                             window=_role_window(cfg, role), angles=angles)
+        x = x + h
+    with jax.named_scope("mlp"):
+        x = x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
     x = constrain(x, ("batch", "seq", "embed"))
     return (x, kv_) if collect_kv else (x, None)
 
@@ -105,35 +113,37 @@ def forward(params, cfg: ModelConfig, tokens, positions=None,
         return xc, tuple(kv_list) if collect_kv else None
 
     wrapped = body  # per-block checkpoints; residuals SP-sharded
-    if cfg.scan_layers and reps > 0:
-        x, ys = jax.lax.scan(wrapped, x, params["blocks"])
-        if collect_kv:
-            kvs["scan"] = ys
-    else:
-        blocks_unstacked = [
-            jax.tree.map(lambda a, g=g: a[g], params["blocks"])
-            for g in range(reps)]
-        ys = []
-        for blk in blocks_unstacked:
-            x, kv_ = wrapped(x, blk)
-            ys.append(kv_)
-        if collect_kv:
-            kvs["scan"] = jax.tree.map(lambda *a: jnp.stack(a), *ys) \
-                if ys else None
-    if "tail" in params:
-        tail_kv = []
-        for i, role in enumerate(cfg.pattern[:cfg.n_layers % P]):
-            x, kv_ = _apply_block(params["tail"][f"p{i}"], cfg, x, positions,
-                                  angles, role, collect_kv)
-            tail_kv.append(kv_)
-        if collect_kv:
-            kvs["tail"] = tuple(tail_kv)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    if return_hidden:
-        return x, (kvs if collect_kv else None)
-    logits = L.unembed(params, cfg, x)
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / 30.0) * 30.0
+    with jax.named_scope("layers"):
+        if cfg.scan_layers and reps > 0:
+            x, ys = jax.lax.scan(wrapped, x, params["blocks"])
+            if collect_kv:
+                kvs["scan"] = ys
+        else:
+            blocks_unstacked = [
+                jax.tree.map(lambda a, g=g: a[g], params["blocks"])
+                for g in range(reps)]
+            ys = []
+            for blk in blocks_unstacked:
+                x, kv_ = wrapped(x, blk)
+                ys.append(kv_)
+            if collect_kv:
+                kvs["scan"] = jax.tree.map(lambda *a: jnp.stack(a), *ys) \
+                    if ys else None
+        if "tail" in params:
+            tail_kv = []
+            for i, role in enumerate(cfg.pattern[:cfg.n_layers % P]):
+                x, kv_ = _apply_block(params["tail"][f"p{i}"], cfg, x,
+                                      positions, angles, role, collect_kv)
+                tail_kv.append(kv_)
+            if collect_kv:
+                kvs["tail"] = tuple(tail_kv)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        if return_hidden:
+            return x, (kvs if collect_kv else None)
+        logits = L.unembed(params, cfg, x)
+        if cfg.logit_softcap:
+            logits = jnp.tanh(logits / 30.0) * 30.0
     return logits, (kvs if collect_kv else None)
 
 
@@ -165,11 +175,13 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, abstract=False):
 
 
 def _decode_block(p, cfg, x, ck, cv, pos, role):
-    h = L.rmsnorm(x, p["pre_attn"], cfg.norm_eps)
-    h, ck, cv = L.attention_decode(p["attn"], cfg, h, ck, cv, pos,
-                                   window=_role_window(cfg, role))
-    x = x + h
-    x = x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
+    with jax.named_scope("attn"):
+        h = L.rmsnorm(x, p["pre_attn"], cfg.norm_eps)
+        h, ck, cv = L.attention_decode(p["attn"], cfg, h, ck, cv, pos,
+                                       window=_role_window(cfg, role))
+        x = x + h
+    with jax.named_scope("mlp"):
+        x = x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
     return x, ck, cv
 
 
@@ -189,25 +201,29 @@ def decode_step(params, cfg: ModelConfig, cache, token, pos):
             new_caches[f"p{i}"] = (ck, cv)
         return xc, new_caches
 
-    if cfg.scan_layers and reps > 0:
-        scan_cache = {k: v for k, v in cache.items() if k.startswith("p")}
-        x, new_scan = jax.lax.scan(body, x, (params["blocks"], scan_cache))
-    else:
-        new_list = []
-        for g in range(reps):
-            blk = jax.tree.map(lambda a, g=g: a[g], params["blocks"])
-            sc = {k: jax.tree.map(lambda a, g=g: a[g], v)
-                  for k, v in cache.items() if k.startswith("p")}
-            x, nc = body(x, (blk, sc))
-            new_list.append(nc)
-        new_scan = jax.tree.map(lambda *a: jnp.stack(a), *new_list) \
-            if new_list else {}
-    new_cache = dict(new_scan)
-    for i, role in enumerate(cfg.pattern[:cfg.n_layers % P]):
-        ck, cv = cache[f"tail{i}"]
-        x, ck, cv = _decode_block(params["tail"][f"p{i}"], cfg, x, ck, cv,
-                                  pos, role)
-        new_cache[f"tail{i}"] = (ck, cv)
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    logits = L.unembed(params, cfg, x)
+    with jax.named_scope("layers"):
+        if cfg.scan_layers and reps > 0:
+            scan_cache = {k: v for k, v in cache.items()
+                          if k.startswith("p")}
+            x, new_scan = jax.lax.scan(body, x,
+                                       (params["blocks"], scan_cache))
+        else:
+            new_list = []
+            for g in range(reps):
+                blk = jax.tree.map(lambda a, g=g: a[g], params["blocks"])
+                sc = {k: jax.tree.map(lambda a, g=g: a[g], v)
+                      for k, v in cache.items() if k.startswith("p")}
+                x, nc = body(x, (blk, sc))
+                new_list.append(nc)
+            new_scan = jax.tree.map(lambda *a: jnp.stack(a), *new_list) \
+                if new_list else {}
+        new_cache = dict(new_scan)
+        for i, role in enumerate(cfg.pattern[:cfg.n_layers % P]):
+            ck, cv = cache[f"tail{i}"]
+            x, ck, cv = _decode_block(params["tail"][f"p{i}"], cfg, x, ck,
+                                      cv, pos, role)
+            new_cache[f"tail{i}"] = (ck, cv)
+    with jax.named_scope("head"):
+        x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        logits = L.unembed(params, cfg, x)
     return logits, new_cache

@@ -239,12 +239,13 @@ def attention(p, cfg: ModelConfig, x, positions, *, causal=True, window=0,
         kpos = positions
     else:
         kpos = jnp.arange(Sk)
-    if S > CHUNK_THRESHOLD:
-        y = gqa_attend_chunked(q, k, v, positions, kpos, causal=causal,
-                               window=window, softcap=cfg.logit_softcap)
-    else:
-        m = _mask(positions, kpos, causal, window)[None, None]
-        y = gqa_attend(q, k, v, m, cfg.logit_softcap)
+    with jax.named_scope("attend"):
+        if S > CHUNK_THRESHOLD:
+            y = gqa_attend_chunked(q, k, v, positions, kpos, causal=causal,
+                                   window=window, softcap=cfg.logit_softcap)
+        else:
+            m = _mask(positions, kpos, causal, window)[None, None]
+            y = gqa_attend(q, k, v, m, cfg.logit_softcap)
     y = y @ p["wo"]
     if cfg.use_bias:
         y = y + p["bo"]
@@ -276,16 +277,20 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
         ang = rope_angles(posv, hd, theta)
         q = apply_rope(q, ang)
         k_new = apply_rope(k_new, ang)
-    slot = pos % C if window > 0 else pos  # ring buffer vs linear cache
-    cache_k = jax.lax.dynamic_update_slice(cache_k, k_new, (0, slot, 0, 0))
-    cache_v = jax.lax.dynamic_update_slice(cache_v, v_new, (0, slot, 0, 0))
-    idx = jnp.arange(C)
-    if window > 0:
-        valid = idx < jnp.minimum(pos + 1, C)
-    else:
-        valid = idx <= pos
-    m = jnp.broadcast_to(valid[None, None, :], (B, 1, C))[:, None]
-    y = gqa_attend(q, cache_k, cache_v, m, cfg.logit_softcap)
+    with jax.named_scope("kv_cache"):
+        slot = pos % C if window > 0 else pos  # ring buffer vs linear cache
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k_new,
+                                               (0, slot, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v_new,
+                                               (0, slot, 0, 0))
+        idx = jnp.arange(C)
+        if window > 0:
+            valid = idx < jnp.minimum(pos + 1, C)
+        else:
+            valid = idx <= pos
+        m = jnp.broadcast_to(valid[None, None, :], (B, 1, C))[:, None]
+    with jax.named_scope("attend"):
+        y = gqa_attend(q, cache_k, cache_v, m, cfg.logit_softcap)
     y = y @ p["wo"]
     if cfg.use_bias:
         y = y + p["bo"]
@@ -343,10 +348,11 @@ def embed_spec(cfg: ModelConfig) -> dict:
 
 
 def embed(p, cfg: ModelConfig, tokens):
-    x = jnp.take(p["embed"], tokens, axis=0).astype(cfg.jdtype)
-    if cfg.family in ("dense", "moe", "vlm"):
-        x = x * math.sqrt(cfg.d_model)  # gemma-style scaling
-    return constrain(x, ("batch", "seq", "embed"))
+    with jax.named_scope("embed"):
+        x = jnp.take(p["embed"], tokens, axis=0).astype(cfg.jdtype)
+        if cfg.family in ("dense", "moe", "vlm"):
+            x = x * math.sqrt(cfg.d_model)  # gemma-style scaling
+        return constrain(x, ("batch", "seq", "embed"))
 
 
 def unembed(p, cfg: ModelConfig, x):

@@ -34,6 +34,11 @@ def schedule(cfg: AdamWConfig, step):
 
 
 def update(grads, state, params, cfg: AdamWConfig):
+    with jax.named_scope("optimizer"):
+        return _update(grads, state, params, cfg)
+
+
+def _update(grads, state, params, cfg: AdamWConfig):
     step = state["step"] + 1
     if cfg.clip_norm:
         gnorm = jnp.sqrt(sum(

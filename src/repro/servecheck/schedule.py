@@ -18,14 +18,12 @@ from functools import partial
 from typing import Dict, List, Optional, Tuple
 
 from ..api.report import Report
-from ..api.runner import _engine_opts
+from ..api.runner import _engine_opts, prove
 from ..api.spec import Degree, task_id
-from ..core import (RefinementError, capture, capture_spmd, check_refinement,
-                    expand_spmd)
+from ..core import RefinementError
 from ..core.explain import aggregate_explanations
-from ..core.terms import pretty
 from ..modelcheck.obligations import Obligation
-from ..modelcheck.stitch import expected_output_relation
+from ..modelcheck.schedule import certified_stats, seam_check
 from ..obs import trace as obs_trace
 from ..runtime import (RuntimeTask, pool_stats, resolve_cache, run_tasks,
                        serve_cache_key)
@@ -50,21 +48,19 @@ def _verify_obligation(ob: Obligation, name: str, expected: str,
     bug = dict(ob.structure).get("bug", "-")
     bug = None if bug == "-" else bug
     degree = tuple(s for _, s in ob.mesh_axes)
+    phase_s: dict = {}
     t0 = time.perf_counter()
     try:
         with _engine_opts(engine_opts) as eo:
-            gs = capture(ob.seq_fn, list(ob.avals), list(ob.input_names))
-            cap = capture_spmd(ob.dist_fn, dict(ob.mesh_axes),
-                               list(ob.in_specs), list(ob.avals),
-                               list(ob.input_names))
-            gd, r_i = expand_spmd(cap)
-            cert = check_refinement(gs, gd, r_i, max_nodes=eo.max_nodes,
-                                    explain=eo.explain)
+            gs, gd, cert = prove(ob.seq_fn, ob.dist_fn, dict(ob.mesh_axes),
+                                 ob.in_specs, ob.avals, ob.input_names, eo,
+                                 name, phase_s)
     except RefinementError as e:
         return Report(
             case=name, degree=degree, bug=bug,
             verdict="refinement_error", expected=expected,
             ok=expected == "refinement_error", localization=e.payload(),
+            stats={"phase_s": phase_s},
             explanation=getattr(e, "explanation", None),
             wall_s=round(time.perf_counter() - t0, 6)).to_json()
     except Exception as e:  # noqa: BLE001 — capture/engine failure -> verdict
@@ -74,31 +70,14 @@ def _verify_obligation(ob: Obligation, name: str, expected: str,
             error=f"{type(e).__name__}: {e}",
             wall_s=round(time.perf_counter() - t0, 6)).to_json()
 
-    # seam check: each distributed cache/read output must assemble exactly
-    # as its PartitionSpec promises the next decode step's input relation
-    n_ranks = 1
-    for _, s in ob.mesh_axes:
-        n_ranks *= s
-    seams, seams_ok = [], True
-    for j, (out_name, ospec) in enumerate(zip(gs.outputs, ob.out_specs)):
-        gd_out = gd.outputs[j * n_ranks]
-        base = gd_out.split("@")[0]
-        expect = expected_output_relation(
-            base, gd.shapes[gd_out], gd.dtypes[gd_out], ospec,
-            dict(ob.mesh_axes))
-        got = cert.r_o.get(out_name)
-        ok = got is expect               # Terms are hash-consed: identity
-        seams_ok &= ok
-        seams.append({"output": out_name, "ok": ok,
-                      "expected": pretty(expect, 999),
-                      "got": None if got is None else pretty(got, 999)})
+    seams, seams_ok = seam_check(ob, gs, gd, cert)
     cert_json = cert.to_json()
     ok = seams_ok if expected == "certificate" else \
         (expected == "unexpected_relation" and not seams_ok)
     d = Report(
         case=name, degree=degree, bug=bug,
         verdict="certificate", expected=expected, ok=ok,
-        r_o=cert_json["r_o"], stats=cert_json["stats"],
+        r_o=cert_json["r_o"], stats=certified_stats(cert_json, phase_s),
         explanation=cert.explanation,
         wall_s=round(time.perf_counter() - t0, 6)).to_json()
     d["seams"] = seams
@@ -208,60 +187,61 @@ def check_serve(strategy: str, *, degree: Optional[Degree] = None,
         raise ValueError(
             f"bug `{bug}` is not hosted by serve strategy `{strategy}` "
             f"(hosted: {sorted(entry.bug_names()) or '-'})")
-    obset = entry.build(degree=degree, bug=bug)
+    with obs_trace.span("decompose", cat="capture"):
+        obset = entry.build(degree=degree, bug=bug)
     obs_trace.event("dedup", cat="engine", subsystem="servecheck",
                     total=obset.total_blocks, unique=obset.n_unique)
     reports, used, cache_stats, pstats = run_serve_obligations(
         strategy, degree, bug=bug, workers=workers,
         engine_opts=engine_opts, timeout_s=timeout_s, cache=cache)
+    with obs_trace.span("stitch", cat="engine"):
+        steps: List[StepResult] = []
+        failing: List[str] = []
+        seen: set = set()
+        for name, key in obset.blocks:
+            rep = reports[key]
+            ob = obset.unique[key]
+            seams = rep.get("seams") or []
+            relation_ok = all(s["ok"] for s in seams) if seams else \
+                rep["verdict"] == "certificate"
+            loc = rep.get("localization") or {}
+            steps.append(StepResult(
+                step=name, pos_class=dict(ob.structure)["pos_class"],
+                obligation=key, verdict=rep["verdict"],
+                relation_ok=relation_ok, cached=key in seen,
+                localized_op=loc.get("op_name")))
+            seen.add(key)
+            if rep["verdict"] != "certificate" or not relation_ok:
+                failing.append(name)
 
-    steps: List[StepResult] = []
-    failing: List[str] = []
-    seen: set = set()
-    for name, key in obset.blocks:
-        rep = reports[key]
-        ob = obset.unique[key]
-        seams = rep.get("seams") or []
-        relation_ok = all(s["ok"] for s in seams) if seams else \
-            rep["verdict"] == "certificate"
-        loc = rep.get("localization") or {}
-        steps.append(StepResult(
-            step=name, pos_class=dict(ob.structure)["pos_class"],
-            obligation=key, verdict=rep["verdict"],
-            relation_ok=relation_ok, cached=key in seen,
-            localized_op=loc.get("op_name")))
-        seen.add(key)
-        if rep["verdict"] != "certificate" or not relation_ok:
-            failing.append(name)
+        verdicts = {s.verdict for s in steps}
+        if verdicts & {"error", "timeout"}:
+            verdict = "error"
+        elif "refinement_error" in verdicts:
+            verdict = "refinement_error"
+        elif any(not s.relation_ok for s in steps):
+            verdict = "unexpected_relation"
+        else:
+            verdict = "certificate"
 
-    verdicts = {s.verdict for s in steps}
-    if verdicts & {"error", "timeout"}:
-        verdict = "error"
-    elif "refinement_error" in verdicts:
-        verdict = "refinement_error"
-    elif any(not s.relation_ok for s in steps):
-        verdict = "unexpected_relation"
-    else:
-        verdict = "certificate"
+        bug_step = entry.bug_steps.get(bug) if bug else None
+        if bug is None:
+            ok = verdict == "certificate"
+        else:
+            # the injected serving bug must surface the way its BugSpec
+            # declares (refinement_error raise, or unexpected_relation via
+            # the cache seam) AND localize to exactly its decode step — the
+            # position-class siblings of the bugged step must stay clean
+            ok = (verdict == entry.bug_spec(bug).expected
+                  and failing == [f"step{bug_step}"])
 
-    bug_step = entry.bug_steps.get(bug) if bug else None
-    if bug is None:
-        ok = verdict == "certificate"
-    else:
-        # the injected serving bug must surface the way its BugSpec
-        # declares (refinement_error raise, or unexpected_relation via
-        # the cache seam) AND localize to exactly its decode step — the
-        # position-class siblings of the bugged step must stay clean
-        ok = (verdict == entry.bug_spec(bug).expected
-              and failing == [f"step{bug_step}"])
-
-    return ServeReport(
-        strategy=strategy, degree=degree, verdict=verdict, ok=ok,
-        steps=steps, reports=dict(reports),
-        total_steps=obset.total_blocks,
-        unique_obligations=obset.n_unique,
-        dedup_ratio=round(obset.dedup_ratio, 3),
-        failing_steps=failing, bug=bug, bug_step=bug_step,
-        wall_s=round(time.perf_counter() - t0, 6), workers=used,
-        cache=cache_stats, pool=pstats,
-        explanation=aggregate_explanations(reports))
+        return ServeReport(
+            strategy=strategy, degree=degree, verdict=verdict, ok=ok,
+            steps=steps, reports=dict(reports),
+            total_steps=obset.total_blocks,
+            unique_obligations=obset.n_unique,
+            dedup_ratio=round(obset.dedup_ratio, 3),
+            failing_steps=failing, bug=bug, bug_step=bug_step,
+            wall_s=round(time.perf_counter() - t0, 6), workers=used,
+            cache=cache_stats, pool=pstats,
+            explanation=aggregate_explanations(reports))

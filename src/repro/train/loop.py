@@ -25,17 +25,19 @@ CE_CHUNKS = 8   # sequence-chunked vocab-parallel CE (bounds logits memory)
 
 
 def _ce_piece(cfg, tcfg, w, xc, lc):
-    """CE over one sequence chunk; logits never materialize for full S."""
-    logits = (xc @ w.astype(xc.dtype)).astype(jnp.float32)
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / 30.0) * 30.0
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)
-    tgt = jnp.take_along_axis(
-        logits, jnp.clip(lc, 0)[..., None], axis=-1)[..., 0]
-    mask = (lc >= 0).astype(jnp.float32)
-    nll = -((tgt - lse) * mask).sum()
-    z = jnp.square(lse * mask).sum() if tcfg.z_loss else jnp.zeros(())
-    return nll, mask.sum(), z
+    """CE over one sequence chunk; logits never materialize for full S.
+    It computes the logits itself, so it is the model's ``head`` scope."""
+    with jax.named_scope("head"):
+        logits = (xc @ w.astype(xc.dtype)).astype(jnp.float32)
+        if cfg.logit_softcap:
+            logits = jnp.tanh(logits / 30.0) * 30.0
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        tgt = jnp.take_along_axis(
+            logits, jnp.clip(lc, 0)[..., None], axis=-1)[..., 0]
+        mask = (lc >= 0).astype(jnp.float32)
+        nll = -((tgt - lse) * mask).sum()
+        z = jnp.square(lse * mask).sum() if tcfg.z_loss else jnp.zeros(())
+        return nll, mask.sum(), z
 
 
 def make_loss_fn(cfg: ModelConfig, tcfg: TrainConfig = TrainConfig()):

@@ -128,5 +128,15 @@ def test_observability_doc_names_key_spans():
     doc = _read("docs", "OBSERVABILITY.md")
     for name in ("capture", "infer", "saturate", "extract", "task",
                  "queue", "run", "saturate.batch", "cache.probe",
-                 "task.retry", "task.timeout", "pool.degraded"):
+                 "task.retry", "task.timeout", "pool.degraded",
+                 "decompose", "expand", "seam", "stitch"):
         assert f"`{name}`" in doc, name
+
+
+def test_observability_doc_names_every_device_scope():
+    import sys
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks", "chip"))
+    import scopes
+    doc = _read("docs", "OBSERVABILITY.md")
+    for name in scopes.SCOPES:
+        assert f"| `{name}` |" in doc, name

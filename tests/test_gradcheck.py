@@ -174,11 +174,14 @@ def test_json_envelope_all_paths(capsys, kind, argv):
     assert env["schema_version"] == 2
     assert env["kind"] == kind
     assert set(env) == {"schema_version", "kind", "timing", "report"}
-    # timing.phase_s keys are the engine's stable phase names
+    # timing.phase_s keys are the engine's stable phase names, and a
+    # model check's obligations add their capture and SPMD expansion
     phases = env["timing"].get("phase_s") or env["timing"].get("phase_s_sum")
     assert phases is not None
-    assert set(phases) <= {"saturate", "rebuild", "frontier", "extract"}
+    assert set(phases) <= {"saturate", "rebuild", "frontier", "extract",
+                           "capture", "expand"}
     assert {"saturate", "extract"} <= set(phases)
+    assert ({"capture", "expand"} <= set(phases)) == (kind == "model")
     blob = json.dumps(env, indent=2, sort_keys=True)
     assert json.dumps(json.loads(blob), indent=2, sort_keys=True) == blob
 

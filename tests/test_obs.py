@@ -13,11 +13,13 @@ import pytest
 from repro import obs
 from repro.api import Suite, verify
 from repro.launch.verify import main as verify_main
+from repro.modelcheck import check_model
 from repro.obs import trace as obs_trace
 from repro.obs.inspect import lemma_totals, obligation_rows, render, report
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.metrics import render as render_metrics
 from repro.runtime import RuntimeTask, SupervisedPool
+from repro.servecheck import check_serve
 
 
 @pytest.fixture(autouse=True)
@@ -159,6 +161,43 @@ def test_certificate_byte_identical_tracing_on_off():
         json.dumps(on.r_o, sort_keys=True)
     for k in ("lemmas", "lemma_fires", "gs_ops", "gd_ops", "egraph_nodes"):
         assert off.stats[k] == on.stats[k], k
+
+
+CHECKER_SPANS = {"decompose", "capture", "expand", "infer", "seam",
+                 "stitch"}
+
+
+@pytest.mark.parametrize("check", [
+    lambda: check_model("gpt", "dp2", workers=0),
+    lambda: check_model("gpt", "dp2xtp2", bug="wrong_spec", bug_layer=1,
+                        workers=0),
+    lambda: check_serve("tp_decode", workers=0),
+], ids=["model", "model_refuted", "serve"])
+def test_checker_spans_behaviour_neutral(check):
+    """The whole-model and serving checks name their capture phases as
+    spans; with the tracer on or off the verdict is the same, and each
+    obligation's ``phase_s`` holds ``capture`` and ``expand`` either way,
+    in a refuted report as in a certified one."""
+    off = check()
+    tracer = obs_trace.start("main")
+    on = check()
+    obs_trace.stop()
+    assert off.stable_summary() == on.stable_summary()
+    for key, rep in off.reports.items():
+        assert rep.get("r_o") == on.reports[key].get("r_o")
+        for r in (rep, on.reports[key]):
+            phases = r["stats"]["phase_s"]
+            assert phases["capture"] > 0 and phases["expand"] > 0
+    names = {e["name"] for e in _spans(tracer.events)}
+    assert CHECKER_SPANS <= names
+    graphs = {e["args"]["graph"] for e in _spans(tracer.events)
+              if e["name"] == "capture"}
+    assert graphs == {"gs", "gd"}
+    # the reports' phase sums carry the two phases, inside the wall time
+    timing = on.timing()
+    captured = timing["phase_s_sum"]["capture"] + \
+        timing["phase_s_sum"]["expand"]
+    assert 0 < captured <= timing["wall_s"] - timing["infer_s_sum"]
 
 
 def test_lemma_stats_deterministic_across_worker_counts():
