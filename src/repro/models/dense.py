@@ -8,6 +8,9 @@ Layers are grouped by the attention *pattern* (e.g. 5 local + 1 global) and
 scanned over pattern groups; any remainder layers get their own unscanned
 parameter stack. Per-role KV caches (ring-buffer for "local" layers, linear
 for "global") keep decode memory at the architecture's true footprint.
+In decode the stacked caches are the layer loop's carry: each layer writes
+its token's slot in place and attends over its own layer of the stack, so
+a donated cache is never copied whole.
 
 The device work of a step is named with ``jax.named_scope``: ``embed``,
 ``layers`` (the layer scan and loops), ``attn`` (with ``kv_cache`` and
@@ -22,6 +25,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..sharding.specs import constrain
 from .config import ModelConfig
@@ -157,7 +161,12 @@ def cache_size(cfg: ModelConfig, role: str, max_seq: int) -> int:
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int, abstract=False):
     """Per-pattern-position stacked KV caches.
-    Layout: {"p{i}": (k, v)} with k: (reps, B, C_i, KV, hd)."""
+    Layout: {"p{i}": (k, v)} with k: (reps, B, C_i, KV, hd), and the tail
+    layers' unstacked {"tail{i}": (k, v)} with k: (B, C_i, KV, hd).
+
+    :func:`decode_step` carries each stack through its layer loop and
+    writes one token's slot of layer ``l`` in place, so a donated cache is
+    updated where it lies and never copied whole."""
     P = len(cfg.pattern)
     reps, tail = cfg.n_layers // P, cfg.n_layers % P
     mk = (lambda s: jax.ShapeDtypeStruct(s, cfg.jdtype)) if abstract \
@@ -174,50 +183,89 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int, abstract=False):
     return cache
 
 
+def _decode_mlp(p, cfg, x):
+    with jax.named_scope("mlp"):
+        return x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
+
+
 def _decode_block(p, cfg, x, ck, cv, pos, role):
+    """A tail layer: its own unstacked cache (B, C, KV, hd)."""
     with jax.named_scope("attn"):
         h = L.rmsnorm(x, p["pre_attn"], cfg.norm_eps)
         h, ck, cv = L.attention_decode(p["attn"], cfg, h, ck, cv, pos,
                                        window=_role_window(cfg, role))
         x = x + h
-    with jax.named_scope("mlp"):
-        x = x + L.mlp(p["mlp"], L.rmsnorm(x, p["pre_mlp"], cfg.norm_eps))
-    return x, ck, cv
+    return _decode_mlp(p, cfg, x), ck, cv
+
+
+def _row_major(a):
+    """``a`` pinned to the row-major layout, the one a jitted step's
+    arguments and results have."""
+    return with_layout_constraint(
+        a, Layout(major_to_minor=tuple(range(a.ndim))))
+
+
+def _decode_stacked_block(p, cfg, x, ks, vs, l, pos, role):
+    """Layer ``l`` of a stacked cache ks/vs: (reps, B, C, KV, hd).  The new
+    token is written into the stack in place, then layer ``l`` is read out
+    of it and attended over.
+
+    The written stack keeps the row-major layout of the donated cache.
+    Left free, the TPU compiler gives the loop's carry the layout that the
+    attention's product prefers and relayouts the whole stack into the loop
+    and back out of it, four copies of the whole cache a step."""
+    window = _role_window(cfg, role)
+    with jax.named_scope("attn"):
+        h = L.rmsnorm(x, p["pre_attn"], cfg.norm_eps)
+        q, k_new, v_new = L.decode_qkv(p["attn"], cfg, h, pos)
+        with jax.named_scope("kv_cache"):
+            slot = L.cache_slot(pos, ks.shape[2], window)
+            ks = _row_major(jax.lax.dynamic_update_slice(
+                ks, k_new[None], (l, 0, slot, 0, 0)))
+            vs = _row_major(jax.lax.dynamic_update_slice(
+                vs, v_new[None], (l, 0, slot, 0, 0)))
+            ck = jax.lax.dynamic_index_in_dim(ks, l, 0, keepdims=False)
+            cv = jax.lax.dynamic_index_in_dim(vs, l, 0, keepdims=False)
+        x = x + L.decode_attend(p["attn"], cfg, q, ck, cv, pos,
+                                window=window)
+    return _decode_mlp(p, cfg, x), ks, vs
 
 
 def decode_step(params, cfg: ModelConfig, cache, token, pos):
-    """token: (B, 1) int32; pos: scalar int32. Returns (logits, new cache)."""
+    """token: (B, 1) int32; pos: scalar int32. Returns (logits, new cache).
+
+    The stacked caches ride in the layer loop's carry beside the residual:
+    layer ``l`` writes its token's K/V into its own layer of the stack, at
+    the token's slot, in place, and attends over that layer.  The loop's
+    inputs are the layer parameters and ``l`` alone and it returns no
+    cache, so under donation the cache is never copied whole.  The unrolled
+    path (``scan_layers=False``) runs the same body with ``l`` a Python
+    int."""
     x = L.embed(params, cfg, token)
     P = len(cfg.pattern)
     reps = cfg.n_layers // P
 
-    def body(xc, blk_and_cache):
-        blk = blk_and_cache[0]
-        new_caches = {}
+    def body(carry, blk_and_l):
+        xc, stacks = carry
+        blk, l = blk_and_l
+        stacks = dict(stacks)
         for i, role in enumerate(cfg.pattern):
-            ck, cv = blk_and_cache[1][f"p{i}"]
-            xc, ck, cv = _decode_block(blk[f"p{i}"], cfg, xc, ck, cv, pos,
-                                       role)
-            new_caches[f"p{i}"] = (ck, cv)
-        return xc, new_caches
+            ks, vs = stacks[f"p{i}"]
+            xc, ks, vs = _decode_stacked_block(blk[f"p{i}"], cfg, xc, ks, vs,
+                                               l, pos, role)
+            stacks[f"p{i}"] = (ks, vs)
+        return (xc, stacks), None
 
+    stacks = {f"p{i}": cache[f"p{i}"] for i in range(P)}
     with jax.named_scope("layers"):
         if cfg.scan_layers and reps > 0:
-            scan_cache = {k: v for k, v in cache.items()
-                          if k.startswith("p")}
-            x, new_scan = jax.lax.scan(body, x,
-                                       (params["blocks"], scan_cache))
+            (x, stacks), _ = jax.lax.scan(
+                body, (x, stacks), (params["blocks"], jnp.arange(reps)))
         else:
-            new_list = []
-            for g in range(reps):
-                blk = jax.tree.map(lambda a, g=g: a[g], params["blocks"])
-                sc = {k: jax.tree.map(lambda a, g=g: a[g], v)
-                      for k, v in cache.items() if k.startswith("p")}
-                x, nc = body(x, (blk, sc))
-                new_list.append(nc)
-            new_scan = jax.tree.map(lambda *a: jnp.stack(a), *new_list) \
-                if new_list else {}
-        new_cache = dict(new_scan)
+            for l in range(reps):
+                blk = jax.tree.map(lambda a, l=l: a[l], params["blocks"])
+                (x, stacks), _ = body((x, stacks), (blk, l))
+        new_cache = dict(stacks)
         for i, role in enumerate(cfg.pattern[:cfg.n_layers % P]):
             ck, cv = cache[f"tail{i}"]
             x, ck, cv = _decode_block(params["tail"][f"p{i}"], cfg, x, ck,

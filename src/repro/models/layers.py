@@ -252,18 +252,13 @@ def attention(p, cfg: ModelConfig, x, positions, *, causal=True, window=0,
     return constrain(y, ("batch", "seq", "embed")), (k, v)
 
 
-def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
-                     window=0, theta=None, rope=True):
-    """Single-token decode. cache_{k,v}: (B, C, KV, hd). ``window`` selects
-    ring-buffer semantics (C == window) vs linear cache (C == max seq).
-    ``rope=False`` for families whose prefill attention runs unrotated
-    (absolute/sinusoid embeddings, e.g. whisper's decoder self-attention) —
-    decode must rotate exactly when prefill does, or the two paths diverge
-    at every position past 0."""
+def decode_qkv(p, cfg: ModelConfig, x, pos, *, theta=None, rope=True):
+    """The projections of one decode token: ``(q, k_new, v_new)``, each
+    ``(B, 1, heads, hd)``, rotated to ``pos`` unless ``rope=False``.  The
+    first piece of :func:`attention_decode`."""
     B, S1, D = x.shape
     assert S1 == 1
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    C = cache_k.shape[1]
     theta = theta or cfg.rope_theta
     q = (x @ p["wq"]).reshape(B, 1, h, hd)
     if cfg.use_bias:
@@ -277,12 +272,25 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
         ang = rope_angles(posv, hd, theta)
         q = apply_rope(q, ang)
         k_new = apply_rope(k_new, ang)
+    return q, k_new, v_new
+
+
+def cache_slot(pos, C: int, window: int):
+    """The cache position a token at ``pos`` is written to: ``pos % C`` in a
+    ring buffer (``window`` > 0), ``pos`` in a linear cache."""
+    return pos % C if window > 0 else pos
+
+
+def decode_attend(p, cfg: ModelConfig, q, cache_k, cache_v, pos, *,
+                  window=0):
+    """Attention of one decode token over a layer's cache that already holds
+    it: ``q`` from :func:`decode_qkv`, cache_{k,v}: (B, C, KV, hd).  Masks
+    the slots not yet written (ring buffer or linear cache, as ``window``
+    says) and returns ``y @ wo``.  The second piece of
+    :func:`attention_decode`."""
+    B = q.shape[0]
+    C = cache_k.shape[1]
     with jax.named_scope("kv_cache"):
-        slot = pos % C if window > 0 else pos  # ring buffer vs linear cache
-        cache_k = jax.lax.dynamic_update_slice(cache_k, k_new,
-                                               (0, slot, 0, 0))
-        cache_v = jax.lax.dynamic_update_slice(cache_v, v_new,
-                                               (0, slot, 0, 0))
         idx = jnp.arange(C)
         if window > 0:
             valid = idx < jnp.minimum(pos + 1, C)
@@ -294,6 +302,30 @@ def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
     y = y @ p["wo"]
     if cfg.use_bias:
         y = y + p["bo"]
+    return y
+
+
+def attention_decode(p, cfg: ModelConfig, x, cache_k, cache_v, pos, *,
+                     window=0, theta=None, rope=True):
+    """Single-token decode. cache_{k,v}: (B, C, KV, hd). ``window`` selects
+    ring-buffer semantics (C == window) vs linear cache (C == max seq).
+    ``rope=False`` for families whose prefill attention runs unrotated
+    (absolute/sinusoid embeddings, e.g. whisper's decoder self-attention) —
+    decode must rotate exactly when prefill does, or the two paths diverge
+    at every position past 0.
+
+    :func:`decode_qkv`, the token's write into the cache, then
+    :func:`decode_attend`.  The dense family, which keeps its caches
+    stacked over layers, calls the two pieces itself and writes the token
+    into the stack between them."""
+    q, k_new, v_new = decode_qkv(p, cfg, x, pos, theta=theta, rope=rope)
+    with jax.named_scope("kv_cache"):
+        slot = cache_slot(pos, cache_k.shape[1], window)
+        cache_k = jax.lax.dynamic_update_slice(cache_k, k_new,
+                                               (0, slot, 0, 0))
+        cache_v = jax.lax.dynamic_update_slice(cache_v, v_new,
+                                               (0, slot, 0, 0))
+    y = decode_attend(p, cfg, q, cache_k, cache_v, pos, window=window)
     return y, cache_k, cache_v
 
 

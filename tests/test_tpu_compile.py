@@ -107,3 +107,33 @@ def test_gpt_train_step_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+
+
+def test_dense_decode_updates_cache_in_place(one_chip):
+    """Yi-9B's decode step at full width, two layers deep, with the cache
+    donated: the layer loop writes each token into the stacked cache where
+    it lies.  No whole stack is copied into the loop or out of it, and the
+    step's scratch stays under one stack."""
+    cfg = replace(registry.load_config("yi-9b"), n_layers=2)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: _on(one_chip, a.shape, a.dtype), tree)
+
+    params = placed(registry.abstract_params(cfg))
+    cache = placed(registry.init_cache(cfg, 32, 4096, abstract=True))
+    compiled = jax.jit(
+        lambda p, c, t, s: registry.decode_step(p, cfg, c, t, s),
+        donate_argnums=(1,)).lower(
+            params, cache, _on(one_chip, (32, 1), jnp.int32),
+            _on(one_chip, (), jnp.int32)).compile()
+    stack = cache["p0"][0]
+    shape = "bf16[" + ",".join(map(str, stack.shape)) + "]"
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert not [line for line in entry.splitlines()
+                if f"= {shape}" in line and " copy(" in line]
+    mem = compiled.memory_analysis()
+    stack_bytes = stack.size * stack.dtype.itemsize
+    assert mem.alias_size_in_bytes == 2 * stack_bytes
+    assert mem.temp_size_in_bytes < stack_bytes, mem.temp_size_in_bytes
