@@ -1,9 +1,11 @@
 """Greedy decoding of a batch of prompts, a token per sequence a step.
 
-Set-up draws the weights and the prompts from the seed, fills the KV cache
-with the program's ``train/serve.sequential_prefill`` (its last logits give
-each sequence's first token; the positions past the prompt hold zeros, as
-the program's ``init_cache`` makes them) and compiles the program's decode step from
+Set-up draws the weights (with the configuration's family, straight into
+the program's parameter shardings on the mix's mesh) and the prompts from
+the seed, fills the KV cache with the program's
+``train/serve.sequential_prefill`` (its last logits give each sequence's
+first token; the positions past the prompt hold zeros, as the program's
+``init_cache`` makes them) and compiles the program's decode step from
 ``launch/steps.build_decode`` with the greedy choice fed back on the
 device.  The window runs steps until ``seconds`` have passed, reading each
 step's tokens back as a server streams them.  Where the cache would
@@ -12,8 +14,11 @@ prompt.
 
 Once the window has closed and the program's state is freed, the plain
 float32 reference runs over a sample of the sequences, drawn from the seed:
-each prompt with its first answer.  ``served_gap`` is the widest gap by
-which a served token's reference logit lies below the reference's best.
+each prompt with its first answer.  It draws the weights again, spread
+over the cell's chips, and runs layer by layer, each layer gathered whole
+onto every chip and the sampled rows split over the chips.
+``served_gap`` is the widest gap by which a served token's reference logit
+lies below the reference's best.
 """
 from __future__ import annotations
 
@@ -39,6 +44,7 @@ def build(cell, devices, seed: int, wrap=None):
     import weights as W
 
     c, t = cell.config, cell.traffic
+    F = harness.family(c["family"])
     B, P, M = t["batch"], t["prompt"], t["max_seq"]
     cfg = harness.program_config(c)
     mesh = harness.mesh_of(devices, t["mesh"])
@@ -47,8 +53,8 @@ def build(cell, devices, seed: int, wrap=None):
         cfg, shape, mesh, rules_for_config(cfg, mesh))
     if wrap is not None:
         fn = wrap(fn)
-    w = W.dense_weights(c, seed, c["dtype"], devices[0])
-    params = jax.jit(W.to_program, out_shardings=shardings[0],
+    w = F.weights(c, seed, c["dtype"], F.from_program(shardings[0]))
+    params = jax.jit(F.to_program, out_shardings=shardings[0],
                      donate_argnums=0)(w)
     del w
     prompts = W.token_stream(seed, 0, (B, P), c["vocab"])
@@ -119,7 +125,7 @@ def run(cell, devices, *, seed: int, seconds: float, trace: bool,
     if trace:
         jax.profiler.stop_trace()
         from devtrace import extract, reduce
-        tr = reduce(extract(tdir, HOST_SPANS))
+        tr = reduce(extract(tdir, HOST_SPANS), chips=len(devices))
         brk = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
         import shutil
         shutil.rmtree(tdir, ignore_errors=True)
@@ -163,17 +169,15 @@ def reference_inputs(prompts, served, bucket: int):
 def served_gap(cell, seed: int, devices, prompts, served, fp8=False):
     """Widest reference gap of a served token (or, with ``fp8``, of the
     fp8 control's first choice at the same positions)."""
-    import jax.numpy as jnp
-    import reference as R
     import weights as W
     c, t = cell.config, cell.traffic
+    F = harness.family(c["family"])
     tokens, target = reference_inputs(prompts, served, t["ref_bucket"])
-    w = W.dense_weights(c, seed, c["dtype"], devices[0])
+    w = F.weights(c, seed, c["dtype"], W.spread(F.shapes(c), devices))
     if fp8:
-        gaps = R.control_gaps(c, w, jnp.asarray(tokens), t["ref_rows"])
+        gaps = F.control_gaps(c, w, tokens, t["ref_rows"], devices)
     else:
-        gaps = R.served_gaps(c, w, jnp.asarray(tokens),
-                             jnp.asarray(np.maximum(target, 0)),
-                             t["ref_rows"])
+        gaps = F.served_gaps(c, w, tokens, np.maximum(target, 0),
+                             t["ref_rows"], devices)
     gaps = np.asarray(gaps)
     return float(np.max(gaps[target >= 0]))

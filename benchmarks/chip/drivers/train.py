@@ -51,6 +51,7 @@ def build(cell, devices, seed: int, wrap=None):
     import weights as W
 
     c, t = cell.config, cell.traffic
+    F = harness.family(c["family"])
     stated = t["optimizer"]
     got = TrainConfig().optimizer
     for k, v in stated.items():
@@ -65,8 +66,8 @@ def build(cell, devices, seed: int, wrap=None):
     if wrap is not None:
         fn = wrap(fn)
     step = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
-    w0 = W.dense_weights(c, seed, c["dtype"], devices[0])
-    params = jax.jit(W.to_program, out_shardings=shardings[0])(w0)
+    w0 = F.weights(c, seed, c["dtype"], F.from_program(shardings[0]))
+    params = jax.jit(F.to_program, out_shardings=shardings[0])(w0)
     want = jax.tree.map(lambda a: (a.shape, a.dtype), abstract[0])
     have = jax.tree.map(lambda a: (a.shape, a.dtype), params)
     if want != have:
@@ -94,17 +95,17 @@ def _norms(tree):
 def run(cell, devices, *, seed: int, seconds: float, trace: bool,
         t0: float, wrap=None) -> Run:
     import jax
-    import weights as W
 
     t = cell.traffic
+    F = harness.family(cell.config["family"])
     b1 = t["optimizer"]["b1"]
     n_checked = t["checked_steps"]
     step, params, opt, batches, w0 = build(cell, devices, seed, wrap)
     grad_norms = jax.jit(lambda mu: _norms(
-        {k: v / (1 - b1) for k, v in W.from_program(mu).items()}))
+        {k: v / (1 - b1) for k, v in F.from_program(mu).items()}))
     change_norms = jax.jit(lambda p, p0: _norms(
         {k: v.astype("float32") - p0[k].astype("float32")
-         for k, v in W.from_program(p).items()}))
+         for k, v in F.from_program(p).items()}))
     losses, g1 = [], None
     for i in range(n_checked):
         t_step = time.perf_counter()
@@ -146,7 +147,7 @@ def run(cell, devices, *, seed: int, seconds: float, trace: bool,
     if trace:
         jax.profiler.stop_trace()
         from devtrace import extract, reduce
-        tr = reduce(extract(tdir, HOST_SPANS))
+        tr = reduce(extract(tdir, HOST_SPANS), chips=len(devices))
         brk = {"device_ops": tr["device_ops"], "idle_gaps": tr["idle_gaps"]}
         import shutil
         shutil.rmtree(tdir, ignore_errors=True)
@@ -168,19 +169,19 @@ def reference_readings(cell, seed: int, devices, fp8=False) -> dict:
     """The reference's losses, first-gradient and change norms per leaf."""
     import jax
     import jax.numpy as jnp
-    import reference as R
     import weights as W
 
     c, t = cell.config, cell.traffic
+    F = harness.family(c["family"])
     o = t["optimizer"]
     opt = (o["lr"], o["b1"], o["b2"], o["eps"], o["weight_decay"],
            o["clip_norm"], o["warmup_steps"])
-    w0 = W.dense_weights(c, seed, c["dtype"], devices[0])
+    w0 = F.weights(c, seed, c["dtype"], devices[0])
     pool = W.token_stream(seed, 0, (t["pool"], t["batch"], t["seq"] + 1),
                           c["vocab"])
     n = t["checked_steps"]
     batches = [(pool[i, :, :-1], pool[i, :, 1:]) for i in range(n)]
-    losses, g, w3 = R.train(c, opt, w0, batches, n, t["ref_rows_per_block"],
+    losses, g, w3 = F.train(c, opt, w0, batches, n, t["ref_rows_per_block"],
                             fp8=fp8)
     norms = jax.jit(_norms)
     return {"losses": losses,

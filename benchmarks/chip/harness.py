@@ -9,6 +9,8 @@ configuration and a traffic mix.  Each lives in a file of its own:
   ``driver`` (a module under ``drivers/``) that generates and times it;
 - ``metrics/<metric>.py``: one reader per metric, ``read(run)`` returning
   a number or ``None`` when the run has nothing to read;
+- ``families/<family>.py``: the configuration's family: its weights, their
+  layout in the program and its plain reference;
 - ``counts/<family>.py``: operations and bytes from shapes;
 - ``limits/<cell>.json``: the limit of every number that decides the
   cell's ``correct``.
@@ -107,9 +109,15 @@ def load_cell(name: str, bench: Optional[dict] = None,
     if name not in cells:
         raise KeyError(f"unknown workload `{name}` (known: {sorted(cells)})")
     w = cells[name]
+    config = _load_json(base / "configs" / f"{w['config']}.json")
+    if "family" in config and not _family_file(config["family"],
+                                               base).exists():
+        raise FileNotFoundError(
+            f"configuration `{w['config']}` is of the family "
+            f"`{config['family']}`, which has no file "
+            f"{_family_file(config['family'], base)}")
     return Cell(
-        name=name, chips=int(w["chips"]),
-        config=_load_json(base / "configs" / f"{w['config']}.json"),
+        name=name, chips=int(w["chips"]), config=config,
         traffic=_load_json(base / "traffic" / f"{w['traffic']}.json"),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
@@ -128,6 +136,27 @@ def reader(metric: str, base: Path = HERE) -> Callable:
                       "chip_metric_" + metric.replace(".", "_")
                       .replace("-", "_"))
     return mod.read
+
+
+def _family_file(family: str, base: Path) -> Path:
+    return base / "families" / f"{family}.py"
+
+
+def family(name: str, base: Path = HERE):
+    """``families/<name>.py``, which gives a driver by these names:
+    ``SIZE_KEYS``, the configuration file's keys that set the program's
+    sizes (:func:`program_config`); ``shapes(c)`` (leaf name -> shape);
+    ``weights(c, seed, dtype, where)``, every leaf drawn from the seed in
+    one jitted call onto ``where`` (a device, or a sharding per leaf);
+    ``to_program`` and ``from_program``, the benchmark's layout to the
+    program's parameter tree and back; ``served_gaps`` and
+    ``control_gaps``, the reference's gaps of served tokens and of the
+    fp8 control's choices, layer by layer; ``train``, the reference's
+    AdamW steps."""
+    path = _family_file(name, base)
+    if not path.exists():
+        raise FileNotFoundError(f"the family `{name}` has no file {path}")
+    return load_module(path, "chip_family_" + name.replace("-", "_"))
 
 
 def counts(family: str, base: Path = HERE):
@@ -265,23 +294,31 @@ def print_checks(run: Run, stream=None) -> None:
 # the program under test
 # ---------------------------------------------------------------------------
 
-SIZE_KEYS = ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-             "d_ff", "vocab", "rope_theta", "norm_eps", "dtype")
-
-
-def program_config(c: dict):
+def program_config(c: dict, base: Path = HERE):
     """The program's registered configuration with the sizes of the
-    configuration file; a size the file does not cut must agree."""
+    configuration file: every key of the family's ``SIZE_KEYS``, each of
+    which agrees with the program unless the file lists it as reduced."""
     from dataclasses import replace
     from repro.models.registry import load_config
-    base = load_config(c["program_config"])
+    prog = load_config(c["program_config"])
+    if prog.family != c["family"]:
+        raise ValueError(f"{c['name']}: the family is `{c['family']}` in "
+                         f"the configuration file and `{prog.family}` in "
+                         f"the program")
+    keys = family(c["family"], base).SIZE_KEYS
+    missing = [k for k in keys if k not in c]
+    if missing:
+        raise KeyError(f"{c['name']}: the configuration file has no "
+                       f"{missing}, which the family `{c['family']}` sizes")
     cut = set(c.get("reduced", ()))
-    for k in SIZE_KEYS:
-        if k not in cut and getattr(base, k) != c[k]:
+    sizes = {k: tuple(c[k]) if isinstance(c[k], list) else c[k]
+             for k in keys}
+    for k in keys:
+        if k not in cut and getattr(prog, k) != sizes[k]:
             raise ValueError(f"{c['name']}: `{k}` is {c[k]!r} in the "
-                             f"configuration file and {getattr(base, k)!r} "
+                             f"configuration file and {getattr(prog, k)!r} "
                              f"in the program, and not listed as reduced")
-    return replace(base, **{k: c[k] for k in SIZE_KEYS})
+    return replace(prog, **sizes)
 
 
 def mesh_of(devices, shape):

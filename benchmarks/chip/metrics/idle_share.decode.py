@@ -2,6 +2,6 @@
 
 
 def read(run):
-    if run.trace is None or run.cell.traffic["driver"] != "decode":
+    if run.trace is None:
         return None
     return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])

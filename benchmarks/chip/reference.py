@@ -20,7 +20,7 @@ parameters stored in the configured type after every update.
 from __future__ import annotations
 
 import math
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -190,8 +190,42 @@ def train(c: dict, opt: tuple, w, batches, steps: int, rows_per_block: int,
 
 # ---------------------------------------------------------------------------
 # serving: layer by layer, so that the float32 weights of one layer at a
-# time are on the device
+# time are on the device.  Given ``devices``, the rows are split over them
+# and each layer's weights, wherever they are drawn, are gathered whole
+# onto each: every chip runs its own rows.
 # ---------------------------------------------------------------------------
+
+def _placement(devices):
+    """Shardings of the rows and of a layer's weights over ``devices``
+    (None for JAX's default device)."""
+    if devices is None:
+        return None, None
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(devices), ("rows",))
+    return NamedSharding(mesh, P("rows")), NamedSharding(mesh, P())
+
+
+def _put(a, sharding):
+    return a if sharding is None else jax.device_put(a, sharding)
+
+
+@lru_cache(maxsize=None)
+def _placer(whole):
+    return jax.jit(lambda tree: tree, out_shardings=whole)
+
+
+def _whole(tree, whole):
+    """``tree`` placed by ``whole`` on the devices themselves: an
+    all-gather where a leaf is split (``device_put`` would take a split
+    leaf through the host, a layer at a time)."""
+    return tree if whole is None else _placer(whole)(tree)
+
+
+def _items(c: dict) -> tuple:
+    return tuple(sorted((k, v) for k, v in c.items()
+                        if isinstance(v, (int, float, str))))
+
 
 @partial(jax.jit, static_argnums=(0, 3))
 def _layer(cfg_items, lw, x, fp8):
@@ -206,13 +240,15 @@ def _embed(cfg_items, table, tokens):
     return embed(dict(cfg_items), {"embed": table}, tokens)
 
 
-def hidden_layerwise(c: dict, w: dict, tokens, fp8=False):
-    """Final residual of ``tokens`` (B, T), one layer at a time."""
-    items = tuple(sorted((k, v) for k, v in c.items()
-                         if isinstance(v, (int, float, str))))
-    x = _embed(items, w["embed"], tokens)
+def hidden_layerwise(c: dict, w: dict, tokens, fp8=False, devices=None):
+    """Final residual of ``tokens`` (B, T), one layer at a time; ``B`` a
+    multiple of the number of ``devices``."""
+    rows, whole = _placement(devices)
+    items = _items(c)
+    x = _embed(items, _whole(w["embed"], whole), _put(tokens, rows))
     for i in range(c["n_layers"]):
-        x = _layer(items, {k: w[k][i] for k in LAYER}, x, fp8)
+        x = _layer(items, _whole({k: w[k][i] for k in LAYER}, whole), x,
+                   fp8)
     return x
 
 
@@ -237,31 +273,47 @@ def _gap_control(cfg_items, fn_w, un_w, x_ref, x_fp8):
     return jnp.max(ref, -1) - got
 
 
-def served_gaps(c: dict, w: dict, tokens, served, rows: int = 1):
+def _by_blocks(fn, devices, rows: int, *arrays):
+    """``fn`` over blocks of ``rows`` rows per device, the last block
+    filled up with copies of its first row; the results concatenated."""
+    n = rows * (len(devices) if devices is not None else 1)
+    out = []
+    for r in range(0, arrays[0].shape[0], n):
+        block = [a[r:r + n] for a in arrays]
+        k = block[0].shape[0]
+        if k < n:
+            block = [jnp.concatenate([b] + [b[:1]] * (n - k)) for b in block]
+        out.append(fn(*block)[:k])
+    return jnp.concatenate(out, 0)
+
+
+def served_gaps(c: dict, w: dict, tokens, served, rows: int = 1,
+                devices=None):
     """Per position, how far the reference logit of the next served token
     lies below the reference's best.  ``tokens``: (B, T) prompt and served
-    tokens; ``served``: (B, T), the token that followed each position."""
-    items = tuple(sorted((k, v) for k, v in c.items()
-                         if isinstance(v, (int, float, str))))
-    out = []
-    for r in range(0, tokens.shape[0], rows):
-        x = hidden_layerwise(c, w, tokens[r:r + rows])
+    tokens; ``served``: (B, T), the token that followed each position;
+    ``rows`` per device at a time."""
+    rows_s, whole = _placement(devices)
+    items = _items(c)
+    fn_w, un_w = _whole((w["final_norm"], w["unembed"]), whole)
+
+    def gaps(tok, srv):
+        x = hidden_layerwise(c, w, tok, devices=devices)
         with jax.default_matmul_precision("highest"):
-            out.append(_gap_served(items, w["final_norm"], w["unembed"], x,
-                                   served[r:r + rows]))
-    return jnp.concatenate(out, 0)
+            return _gap_served(items, fn_w, un_w, x, _put(srv, rows_s))
+    return _by_blocks(gaps, devices, rows, tokens, served)
 
 
-def control_gaps(c: dict, w: dict, tokens, rows: int = 1):
+def control_gaps(c: dict, w: dict, tokens, rows: int = 1, devices=None):
     """Per position, the reference gap of the fp8 control's first
     choice."""
-    items = tuple(sorted((k, v) for k, v in c.items()
-                         if isinstance(v, (int, float, str))))
-    out = []
-    for r in range(0, tokens.shape[0], rows):
-        x = hidden_layerwise(c, w, tokens[r:r + rows])
-        xq = hidden_layerwise(c, w, tokens[r:r + rows], fp8=True)
+    _, whole = _placement(devices)
+    items = _items(c)
+    fn_w, un_w = _whole((w["final_norm"], w["unembed"]), whole)
+
+    def gaps(tok):
+        x = hidden_layerwise(c, w, tok, devices=devices)
+        xq = hidden_layerwise(c, w, tok, fp8=True, devices=devices)
         with jax.default_matmul_precision("highest"):
-            out.append(_gap_control(items, w["final_norm"], w["unembed"],
-                                    x, xq))
-    return jnp.concatenate(out, 0)
+            return _gap_control(items, fn_w, un_w, x, xq)
+    return _by_blocks(gaps, devices, rows, tokens)

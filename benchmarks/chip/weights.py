@@ -2,7 +2,10 @@
 
 The dense family's weights are drawn in one jitted call on the device, in
 the type they are served in, in the benchmark's own layout (every block's
-leaf stacked over the layers).  :func:`to_program` arranges them into the
+leaf stacked over the layers), straight into the shardings they are used
+in: no chip holds more than its share of a leaf, the float32 draw
+included.  Threefry is partitionable (JAX's default), so the values do not
+depend on the sharding.  :func:`to_program` arranges them into the
 program's parameter tree; the reference draws them again from the same
 seed, so it takes nothing the program has made.
 """
@@ -48,18 +51,40 @@ def _draw(shapes, dtype, key):
     return out
 
 
-def dense_weights(c: dict, seed: int, dtype="bfloat16", device=None):
-    """Every weight of ``c`` from ``seed``, in one jitted call on the
-    device (``device``: the first one by default)."""
+def dense_weights(c: dict, seed: int, dtype="bfloat16", where=None):
+    """Every weight of ``c`` from ``seed``, in one jitted call.
+
+    ``where`` places the leaves: a device for all of them, or a dict of
+    shardings keyed like :func:`dense_shapes`; by default JAX's default
+    device."""
     import jax
     import jax.numpy as jnp
     from harness import seed31
     shapes = dense_shapes(c)
-    fn = jax.jit(partial(_draw, shapes, jnp.dtype(dtype)))
-    key = jax.random.PRNGKey(seed31(seed))
-    if device is not None:
-        key = jax.device_put(key, device)
-    return fn(key)
+    if isinstance(where, jax.Device):
+        where = jax.sharding.SingleDeviceSharding(where)
+    fn = jax.jit(partial(_draw, shapes, jnp.dtype(dtype)),
+                 out_shardings=where)
+    return fn(jax.random.PRNGKey(seed31(seed)))
+
+
+def spread(shapes: dict, devices) -> dict:
+    """The reference's own placement of ``shapes`` over ``devices``: each
+    leaf split over all of them along its last axis that they divide
+    (never the first axis of a stacked leaf), or copied whole to each."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(devices), ("d",))
+    n = len(devices)
+    out = {}
+    for k, shape in shapes.items():
+        axes = [a for a in range(len(shape) - 1, -1, -1)
+                if shape[a] % n == 0 and (a > 0 or len(shape) == 1)]
+        spec = [None] * len(shape)
+        if axes and n > 1:
+            spec[axes[0]] = "d"
+        out[k] = NamedSharding(mesh, P(*spec))
+    return out
 
 
 def to_program(w: dict) -> dict:

@@ -17,7 +17,7 @@ TINY_DENSE = {
     "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
     "head_dim": 16, "d_ff": 128, "vocab": 256, "rope_theta": 10000.0,
     "norm_eps": 1e-6, "dtype": "bfloat16",
-    "reduced": list(harness.SIZE_KEYS),
+    "reduced": list(harness.family("dense").SIZE_KEYS),
 }
 
 

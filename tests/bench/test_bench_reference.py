@@ -78,3 +78,23 @@ def test_served_gap_is_zero_for_reference_greedy(tiny32):
     assert float(jnp.max(R.served_gaps(c, w, tokens, best))) == 0.0
     other = (best + 1) % c["vocab"]
     assert float(jnp.min(R.served_gaps(c, w, tokens, other))) > 0.0
+
+
+def test_reference_on_given_devices_is_bit_for_bit(tiny32):
+    """Rows placed on a list of devices, and blocks that do not fill the
+    last one, give the gaps of the default placement exactly."""
+    import jax
+    import numpy as np
+    import reference as R
+    import weights as W
+    c, _, w = tiny32
+    tokens = np.asarray(W.token_stream(4, 0, (3, 16), c["vocab"]))
+    served = np.asarray(W.token_stream(4, 1, (3, 16), c["vocab"]))
+    cpu = jax.devices("cpu")[:1]
+    want = np.asarray(R.served_gaps(c, w, tokens, served))
+    for rows in (1, 2):
+        got = R.served_gaps(c, w, tokens, served, rows, devices=cpu)
+        assert np.array_equal(np.asarray(got), want)
+    want = np.asarray(R.control_gaps(c, w, tokens))
+    got = R.control_gaps(c, w, tokens, 2, devices=cpu)
+    assert np.array_equal(np.asarray(got), want)
