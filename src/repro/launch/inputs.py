@@ -9,7 +9,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..models import registry
+from ..models import dense, registry
 from ..models.config import INPUT_SHAPES, InputShape, ModelConfig
 
 
@@ -80,8 +80,8 @@ def cache_logical(cfg: ModelConfig) -> dict:
     """Logical axes matching registry.init_cache structure."""
     fam = cfg.family
     if fam in ("dense", "vlm", "moe"):
-        kv = ("layers", "batch", "kv_seq", "kv_heads", None)
-        kv_tail = ("batch", "kv_seq", "kv_heads", None)
+        kv = dense.STACK_AXES
+        kv_tail = kv[1:]
         out = {}
         P = len(cfg.pattern)
         for i in range(P):

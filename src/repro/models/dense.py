@@ -10,7 +10,8 @@ parameter stack. Per-role KV caches (ring-buffer for "local" layers, linear
 for "global") keep decode memory at the architecture's true footprint.
 In decode the stacked caches are the layer loop's carry: each layer writes
 its token's slot in place and attends over its own layer of the stack, so
-a donated cache is never copied whole.
+a donated cache is never copied whole, and under a mesh that splits the
+cache (by KV head, in tensor parallelism) no device gathers it.
 
 The device work of a step is named with ``jax.named_scope``: ``embed``,
 ``layers`` (the layer scan and loops), ``attn`` (with ``kv_cache`` and
@@ -25,9 +26,12 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax._src import core as jax_core
+from jax._src import sharding_impls
+from jax._src.interpreters import mlir
 from jax.experimental.layout import Layout, with_layout_constraint
 
-from ..sharding.specs import constrain
+from ..sharding.specs import constrain, logical_sharding
 from .config import ModelConfig
 from . import layers as L
 
@@ -198,11 +202,55 @@ def _decode_block(p, cfg, x, ck, cv, pos, role):
     return _decode_mlp(p, cfg, x), ck, cv
 
 
-def _row_major(a):
-    """``a`` pinned to the row-major layout, the one a jitted step's
-    arguments and results have."""
-    return with_layout_constraint(
-        a, Layout(major_to_minor=tuple(range(a.ndim))))
+# the logical axes of a stacked K/V cache (reps, B, C, KV, hd), as
+# ``launch/inputs.cache_logical`` shards it
+STACK_AXES = ("layers", "batch", "kv_seq", "kv_heads", None)
+
+# A layout pin that holds where the step runs on one device.  Outside
+# ``use_sharding`` (``benchmarks/chip/drivers/decode.py`` jits
+# ``sequential_prefill`` with shardings and no such context) a step over
+# several devices cannot tell the pin its shard, and the partitioner would
+# gather the whole of what a layout constraint holds onto every device:
+# there it lowers to nothing.
+_one_device_pin_p = jax_core.Primitive("one_device_layout_pin")
+_one_device_pin_p.def_impl(lambda a, *, layout: a)
+_one_device_pin_p.def_abstract_eval(lambda a, *, layout: a)
+
+
+def _one_device_pin_lowering(ctx, a, *, layout):
+    axes = ctx.module_context.axis_context
+    if isinstance(axes, sharding_impls.ShardingContext) \
+            and axes.num_devices > 1:
+        return [a]
+    return [mlir.wrap_with_layout_op(ctx, a, ctx.avals_out[0], layout,
+                                     ctx.avals_in[0])]
+
+
+mlir.register_lowering(_one_device_pin_p, _one_device_pin_lowering)
+
+
+def _pinned(a):
+    """``a``, a written K/V stack, pinned to the layout a jitted step's
+    arguments give it: row-major over each device's shard, with the dims
+    a shard holds one of outermost (the TPU tiles the two innermost dims,
+    and a dim of one among them would be padded).
+
+    Where the active mesh splits the stack (``STACK_AXES`` under
+    ``use_sharding``), the pin runs on each device's shard in a
+    ``shard_map``, so the stack keeps its sharding: the partitioner does
+    not split a layout constraint, and would gather the whole stack onto
+    every device in every layer."""
+    sharding = logical_sharding(STACK_AXES)
+    local = a.shape if sharding is None else sharding.shard_shape(a.shape)
+    layout = Layout(major_to_minor=tuple(
+        sorted(range(a.ndim), key=lambda d: local[d] != 1)))
+    if sharding is None:
+        return _one_device_pin_p.bind(a, layout=layout)
+    pin = partial(with_layout_constraint, layouts=layout)
+    if local == a.shape:
+        return pin(a)
+    return jax.shard_map(pin, mesh=sharding.mesh, in_specs=sharding.spec,
+                         out_specs=sharding.spec)(a)
 
 
 def _decode_stacked_block(p, cfg, x, ks, vs, l, pos, role):
@@ -210,19 +258,20 @@ def _decode_stacked_block(p, cfg, x, ks, vs, l, pos, role):
     token is written into the stack in place, then layer ``l`` is read out
     of it and attended over.
 
-    The written stack keeps the row-major layout of the donated cache.
-    Left free, the TPU compiler gives the loop's carry the layout that the
-    attention's product prefers and relayouts the whole stack into the loop
-    and back out of it, four copies of the whole cache a step."""
+    The written stack keeps the layout and the sharding of the donated
+    cache (:func:`_pinned`).  Left free, the TPU compiler gives the loop's
+    carry the layout that the attention's product prefers and relayouts
+    the whole stack into the loop and back out of it, four copies of the
+    whole cache a step."""
     window = _role_window(cfg, role)
     with jax.named_scope("attn"):
         h = L.rmsnorm(x, p["pre_attn"], cfg.norm_eps)
         q, k_new, v_new = L.decode_qkv(p["attn"], cfg, h, pos)
         with jax.named_scope("kv_cache"):
             slot = L.cache_slot(pos, ks.shape[2], window)
-            ks = _row_major(jax.lax.dynamic_update_slice(
+            ks = _pinned(jax.lax.dynamic_update_slice(
                 ks, k_new[None], (l, 0, slot, 0, 0)))
-            vs = _row_major(jax.lax.dynamic_update_slice(
+            vs = _pinned(jax.lax.dynamic_update_slice(
                 vs, v_new[None], (l, 0, slot, 0, 0)))
             ck = jax.lax.dynamic_index_in_dim(ks, l, 0, keepdims=False)
             cv = jax.lax.dynamic_index_in_dim(vs, l, 0, keepdims=False)

@@ -184,13 +184,20 @@ def active_mesh() -> Optional[Mesh]:
     return _ctx.mesh
 
 
+def logical_sharding(logical_axes: tuple) -> Optional[NamedSharding]:
+    """The sharding the active rules give ``logical_axes`` on the active
+    mesh; None when no mesh is active."""
+    if _ctx.mesh is None or _ctx.rules is None:
+        return None
+    return NamedSharding(_ctx.mesh, _ctx.rules.spec_for(logical_axes))
+
+
 def constrain(x, logical_axes: tuple):
     """Apply a sharding constraint if a mesh is active; identity otherwise."""
-    if _ctx.mesh is None or _ctx.rules is None:
+    sharding = logical_sharding(logical_axes)
+    if sharding is None:
         return x
-    spec = _ctx.rules.spec_for(logical_axes)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(_ctx.mesh, spec))
+    return jax.lax.with_sharding_constraint(x, sharding)
 
 
 def _is_axes_leaf(x):

@@ -8,9 +8,18 @@ and writes each layer's token in place.
   position, inside the window and past the ring's wrap.
 - Structure: compiled with the cache donated, the step's entry computation
   copies no whole stacked cache and its output aliases all of the cache.
+- Tensor parallel: on a (1, 4) mesh of CPU devices, through
+  ``launch/steps.build_decode`` with the cache donated, two steps give the
+  logits and caches of one device, and the returned cache is still split
+  by KV head.
 """
 import functools
+import json
+import os
 import re
+import subprocess
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -144,3 +153,88 @@ def test_donated_cache_is_updated_in_place():
     cache_bytes = sum(s.size * s.dtype.itemsize
                       for s in jax.tree.leaves(cache))
     assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
+
+
+# two donated steps of a tiny dense model on a (1, 4) mesh of CPU devices
+# and on one device; the device count has to be set before JAX starts, so
+# the script runs in a process of its own
+TP_SCRIPT = r"""
+import json
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+from repro.launch.mesh import rules_for_config
+from repro.launch.steps import build_decode
+from repro.models import dense
+from repro.models.config import InputShape
+from repro.models.registry import init_params, load_config
+
+devices = jax.devices("cpu")
+assert len(devices) == 4, devices
+cfg = load_config("yi-9b").reduced(n_heads=8, n_kv_heads=4)
+B = 2
+mesh = Mesh(np.array(devices).reshape(1, 4), ("data", "model"),
+            axis_types=(AxisType.Auto,) * 2)
+fn, _, sh, donate = build_decode(
+    cfg, InputShape("t", %(max_seq)d, B, "decode"), mesh,
+    rules_for_config(cfg, mesh))
+params = init_params(cfg, jax.random.PRNGKey(0))
+shapes = dense.init_cache(cfg, B, %(max_seq)d, abstract=True)
+leaves, tree = jax.tree.flatten(shapes)
+keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+cache = jax.tree.unflatten(tree, [jax.random.normal(k, s.shape, s.dtype)
+                                  for k, s in zip(keys, leaves)])
+one = jax.jit(lambda p, c, t, s: dense.decode_step(p, cfg, c, t, s))
+tp = jax.jit(fn, in_shardings=sh, donate_argnums=donate)
+got = jax.device_put((params, cache), sh[:2])
+out = {"logits": 0.0, "cache": 0.0}
+
+
+def rel(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    return float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+
+
+for token, pos in (([[3], [7]], 5), ([[11], [2]], 6)):
+    token, pos = jnp.array(token, jnp.int32), jnp.int32(pos)
+    logits, cache = one(params, cache, token, pos)
+    tp_logits, tp_cache = tp(got[0], got[1], token, pos)
+    got = (got[0], tp_cache)
+    out["logits"] = max(out["logits"], rel(tp_logits, logits))
+    out["cache"] = max([out["cache"]] + [
+        rel(a, b) for a, b in zip(jax.tree.leaves(tp_cache),
+                                  jax.tree.leaves(cache))])
+stack = "[" + ",".join(map(str, leaves[0].shape)) + "]"
+text = tp.lower(*got, token, pos).compile().as_text()
+out["gathers"] = [line.strip()[:200] for line in text.splitlines()
+                  if stack in line.split(" = ", 1)[-1][:40]
+                  and "all-gather" in line]
+want = NamedSharding(mesh, P(None, None, None, "model", None))
+out["split"] = [a.sharding.is_equivalent_to(want, a.ndim)
+                for a in jax.tree.leaves(got[1])]
+out["shard_shape"] = [list(a.addressable_shards[0].data.shape)
+                      for a in jax.tree.leaves(got[1])]
+print(json.dumps(out))
+"""
+
+
+def test_tensor_parallel_decode_keeps_the_cache_split():
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=str(root / "src"))
+    p = subprocess.run([sys.executable, "-c",
+                        TP_SCRIPT % {"max_seq": MAX_SEQ}],
+                       cwd=root, env=env, capture_output=True, text=True,
+                       timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    got = json.loads(p.stdout.strip().splitlines()[-1])
+    # the file's 1e-6, of the largest value: the all-reduce adds the four
+    # devices' partial products in another order than one device does
+    assert got["logits"] <= 1e-6 and got["cache"] <= 1e-6, got
+    # no device gathers a whole stack, and every stack (K and V) still
+    # holds one of the four KV heads a device
+    assert not got["gathers"], got["gathers"]
+    assert got["split"] == [True, True], got
+    assert got["shard_shape"] == [[2, 2, MAX_SEQ, 1, 32]] * 2, got

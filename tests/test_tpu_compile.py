@@ -8,16 +8,21 @@ does not fit the chip's memory.  The topology is described inside a
 fixture, never at import: only one process may load the TPU library, and
 every test worker imports this file.
 """
+import re
 from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
 
 from repro.kernels import flash_attention as fa
 from repro.kernels.rmsnorm import rmsnorm
+from repro.launch.mesh import rules_for_config
+from repro.launch.steps import build_decode
 from repro.models import registry
+from repro.models.config import InputShape
 from repro.optim import adamw
 from repro.train.loop import TrainConfig, make_train_step
 
@@ -137,3 +142,76 @@ def test_dense_decode_updates_cache_in_place(one_chip):
     stack_bytes = stack.size * stack.dtype.itemsize
     assert mem.alias_size_in_bytes == 2 * stack_bytes
     assert mem.temp_size_in_bytes < stack_bytes, mem.temp_size_in_bytes
+
+
+def _tp4(topo):
+    """Yi-9B's steps from `build_decode` on a (1, 4) mesh of the v5e 2x2."""
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = registry.load_config("yi-9b")
+    fn, abstract, shardings, donate = build_decode(
+        cfg, InputShape("tp4", 4096, 32, "decode"), mesh,
+        rules_for_config(cfg, mesh))
+    args = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        abstract, shardings)
+    return cfg, fn, args, shardings, donate
+
+
+def _stack_moves(compiled):
+    """The all-gathers and copies whose result is a K/V stack of Yi-9B's
+    48 layers and 32 sequences, whole or one chip's shard."""
+    return [line.strip()[:200] for line in compiled.as_text().splitlines()
+            if " = " in line
+            and line.split(" = ", 1)[1].lstrip("(").startswith(
+                "bf16[48,32,")
+            and re.search(r" (all-gather|copy)(-start)?\(", line)]
+
+
+def test_yi9b_tensor_parallel_decode_keeps_the_cache_split(topo):
+    """Yi-9B whole, all 48 layers at published widths, greedy decode of 32
+    sequences into a 4096-position cache on a (1, 4) mesh of the v5e 2x2,
+    through ``launch/steps.build_decode`` with the cache donated, as
+    ``benchmarks/chip/drivers/decode.py`` builds it (all 48 layers: the
+    compile takes seconds here).  Each chip holds one of the four KV heads: no K/V stack
+    is gathered or copied, the step fits a chip, and the returned cache is
+    still split by KV head."""
+    cfg, fn, args, shardings, donate = _tp4(topo)
+
+    def greedy(p, cache, tok, pos):
+        logits, cache = fn(p, cache, tok, pos)
+        return jnp.argmax(logits[:, 0], -1).astype(jnp.int32)[:, None], cache
+
+    compiled = jax.jit(greedy, in_shardings=shardings,
+                       donate_argnums=donate).lower(*args).compile()
+    stack = args[1]["p0"][0]
+    assert stack.shape == (48, 32, 4096, 4, 128)
+    assert not _stack_moves(compiled)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
+    shard = stack.size * stack.dtype.itemsize // 4
+    assert mem.alias_size_in_bytes == 2 * shard
+    for out in jax.tree.leaves(compiled.output_shardings[1]):
+        assert out.is_equivalent_to(shardings[1]["p0"][0], stack.ndim)
+
+
+def test_yi9b_tensor_parallel_prefill_gathers_no_stack(topo):
+    """The same cell's prefill in ``drivers/decode.py``:
+    ``sequential_prefill`` of 512 prompt tokens into a 512-position cache,
+    jitted with the cache's shardings outside ``use_sharding``.  No pin can learn the
+    shard there, so none holds on four chips: no stack is gathered onto
+    every chip in each layer of each step, and the prefill fits a chip."""
+    from repro.train.serve import sequential_prefill
+    cfg, _, args, shardings, _ = _tp4(topo)
+    tokens = jax.ShapeDtypeStruct((32, 512), jnp.int32,
+                                  sharding=shardings[2])
+    compiled = jax.jit(
+        lambda p, t: sequential_prefill(p, cfg, t, 512)[0],
+        out_shardings=shardings[1]).lower(args[0], tokens).compile()
+    assert not [m for m in _stack_moves(compiled) if "all-gather" in m]
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < HBM_BYTES, total
