@@ -159,23 +159,38 @@ def _mask(q_pos, k_pos, causal: bool, window: int):
 def gqa_attend(q, k, v, mask, softcap: float = 0.0):
     """q: (B,Sq,H,hd), k/v: (B,Sk,KV,hd), mask broadcastable (B,1,Sq,Sk).
 
-    KV heads are (virtually) expanded to H so the score tensor keeps one
-    fused head dim — XLA folds the repeat into the einsum, and the head
-    dim stays expressible as a single sharded axis (TP over heads)."""
+    With G = H // KV > 1 query heads to a KV head, each KV head's group of
+    query heads is scored against its own K/V once: q is viewed as
+    (B, Sq, KV, G, hd), so both products read each K/V element once and
+    run on the MXU (K/V repeated to H heads would be a tensor of its own,
+    multiplied on the VPU).  K/V are split over KV heads as the cache is
+    (``kv_heads``); the scores, viewed as (B, H, Sq, Sk), over query heads
+    as the query is (``act_heads``).  Where the rules split the query heads
+    and not the KV heads, each device then scores its own query heads
+    against K/V it holds whole, and nothing is gathered."""
     B, Sq, H, hd = q.shape
-    KV = k.shape[2]
+    Sk, KV = k.shape[1], k.shape[2]
     G = H // KV
-    if G > 1:
-        k = jnp.repeat(k, G, axis=2)
-        v = jnp.repeat(v, G, axis=2)
-    scores = jnp.einsum("bqhd,bshd->bhqs", q, k).astype(jnp.float32)
+    if G == 1:
+        scores = jnp.einsum("bqhd,bshd->bhqs", q, k)
+    else:
+        k = constrain(k, ("batch", None, "kv_heads", None))
+        v = constrain(v, ("batch", None, "kv_heads", None))
+        scores = jnp.einsum("bqkgd,bskd->bkgqs",
+                            q.reshape(B, Sq, KV, G, hd), k)
+        scores = scores.reshape(B, H, Sq, Sk)
+    scores = scores.astype(jnp.float32)
     scores *= 1.0 / math.sqrt(hd)
     if softcap:
         scores = jnp.tanh(scores / softcap) * softcap
     scores = jnp.where(mask, scores, -1e30)
     scores = constrain(scores, ("batch", "act_heads", None, None))
-    w = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhqs,bshd->bqhd", w.astype(v.dtype), v)
+    w = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if G == 1:
+        out = jnp.einsum("bhqs,bshd->bqhd", w, v)
+    else:
+        out = jnp.einsum("bkgqs,bskd->bqkgd",
+                         w.reshape(B, KV, G, Sq, Sk), v)
     return out.reshape(B, Sq, H * hd)
 
 

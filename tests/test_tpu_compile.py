@@ -144,11 +144,58 @@ def test_dense_decode_updates_cache_in_place(one_chip):
     assert mem.temp_size_in_bytes < stack_bytes, mem.temp_size_in_bytes
 
 
-def _tp4(topo):
-    """Yi-9B's steps from `build_decode` on a (1, 4) mesh of the v5e 2x2."""
+_ATTEND_PRODUCT = re.compile(r'op_name="[^"]*/attend/[^"/]*/dot_general"')
+
+
+def _computations(text):
+    """``{name: body}`` of each computation of a compiled module's text."""
+    return dict(re.findall(r"(?m)^(?:ENTRY )?%?([\w.\-]+) [^\n]*\{\n(.*?)\n\}",
+                           text, re.S))
+
+
+def test_dense_decode_attends_on_the_mxu(one_chip):
+    """Yi-9B's greedy decode step at full width, two layers deep: its 32
+    query heads attend in groups of 8 over each of the 4 KV heads.  Both
+    of ``attend``'s products (scores and weighted sum) are convolutions
+    inside ``kOutput`` fusions, the MXU's, and none is a multiply-reduce;
+    no K/V is repeated to the 32 query heads (no ``bf16[32,4096,32]``
+    product of the 32 sequences, 4096 positions and 32 heads)."""
+    cfg = replace(registry.load_config("yi-9b"), n_layers=2)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: _on(one_chip, a.shape, a.dtype), tree)
+
+    def greedy(p, c, t, s):
+        logits, c = registry.decode_step(p, cfg, c, t, s)
+        return jnp.argmax(logits[:, 0], -1).astype(jnp.int32)[:, None], c
+
+    text = jax.jit(greedy, donate_argnums=(1,)).lower(
+        placed(registry.abstract_params(cfg)),
+        placed(registry.init_cache(cfg, 32, 4096, abstract=True)),
+        _on(one_chip, (32, 1), jnp.int32),
+        _on(one_chip, (), jnp.int32)).compile().as_text()
+    assert "bf16[32,4096,32]" not in text
+    products = [line for line in text.splitlines()
+                if _ATTEND_PRODUCT.search(line)]
+    opcodes = {re.search(r"\s([a-z][\w\-]*)\(", line.split(" = ", 1)[1])
+               .group(1) for line in products}
+    assert "convolution" in opcodes and "reduce" not in opcodes, opcodes
+    bodies = _computations(text)
+    kinds = {}
+    for line in text.splitlines():
+        m = re.search(r" fusion\(.*kind=(k\w+), calls=%([\w.\-]+)", line)
+        if m and any(" convolution(" in l and _ATTEND_PRODUCT.search(l)
+                     for l in bodies.get(m.group(2), "").splitlines()):
+            kinds[line.split(" = ")[0].strip()] = m.group(1)
+    assert len(kinds) >= 2 and set(kinds.values()) == {"kOutput"}, kinds
+
+
+def _tp4(topo, name="yi-9b", **reduced):
+    """A model's steps from `build_decode` on a (1, 4) mesh of the v5e 2x2:
+    Yi-9B whole unless named."""
     mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
-    cfg = registry.load_config("yi-9b")
+    cfg = replace(registry.load_config(name), **reduced)
     fn, abstract, shardings, donate = build_decode(
         cfg, InputShape("tp4", 4096, 32, "decode"), mesh,
         rules_for_config(cfg, mesh))
@@ -215,3 +262,28 @@ def test_yi9b_tensor_parallel_prefill_gathers_no_stack(topo):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < total < HBM_BYTES, total
+
+
+def test_grouped_decode_gathers_no_more_where_kv_heads_stay_whole(topo):
+    """Qwen2-VL-2B at published widths, two layers deep, greedy decode of
+    32 sequences into a 4096-position cache on the (1, 4) mesh: its 12
+    query heads split 3 a chip, its 2 KV heads do not divide the model axis
+    and stay whole on every chip.  The step gathers no more than the 8
+    all-gathers of the step that repeated K/V to every query head (a
+    compile of that step for the same described v5e 2x2: 6 of a K/V stack
+    in the layer loop, 2 for the argmax), and no K/V stack at all."""
+    cfg, fn, args, shardings, donate = _tp4(topo, "qwen2-vl-2b", n_layers=2)
+
+    def greedy(p, cache, tok, pos):
+        logits, cache = fn(p, cache, tok, pos)
+        return jnp.argmax(logits[:, 0], -1).astype(jnp.int32)[:, None], cache
+
+    text = jax.jit(greedy, in_shardings=shardings,
+                   donate_argnums=donate).lower(*args).compile().as_text()
+    gathers = [line.strip()[:200] for line in text.splitlines()
+               if re.search(r" all-gather(-start)?\(", line)]
+    assert len(gathers) <= 8, gathers
+    stack = "bf16[" + ",".join(map(str, args[1]["p0"][0].shape[:2]))
+    assert not [g for g in gathers
+                if g.split(" = ", 1)[1].lstrip("(").startswith(stack)], \
+        gathers
